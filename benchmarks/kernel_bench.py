@@ -802,6 +802,8 @@ def main() -> None:
     ap.add_argument("--out", default=str(Path(__file__).parent
                                          / "BENCH_streaming.json"))
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.bench_streaming:
         bench_streaming(args.fleet, args.chunk, args.budget_mb,

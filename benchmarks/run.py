@@ -278,6 +278,8 @@ def main() -> None:
                     help="validate the CSV schema of every emitted row; "
                          "exit nonzero on a malformed one")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     failures = []
 

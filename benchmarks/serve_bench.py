@@ -51,36 +51,6 @@ TICK_DT_YEARS = 1.0          # re-profiling cadence of the bench's fleet life
 LIFE_YEARS = 3.0             # ticks at 1.0 .. 3.0 (past the 2.5y horizon)
 
 
-def _oracle_parity(server, fleet, n_prefix: int) -> dict:
-    """Compare every served prefix table against the dense oracle for its
-    path: hit/discover vs ``diva_profile`` (region="worst"), conventional
-    vs the every-row sweep (region="all").  Tables must match bit for bit
-    AT THE AGE THEY WERE PROFILED, so this runs before any tick."""
-    import dataclasses
-
-    from repro.core.substrate import profile_population_arrays
-    from repro.serve import PATH_CONVENTIONAL
-
-    batch = fleet.chunk(0, n_prefix)
-    aged = dataclasses.replace(
-        batch, age_years=np.full(batch.n_dimms, np.float32(server.clock)))
-    kw = dict(temp_C=server.cfg.profile_temp_C,
-              refresh_ms=server.cfg.profile_refresh_ms,
-              guard_cycles=server.cfg.guard_cycles,
-              multibit_only=server.cfg.multibit_only)
-    diva = np.asarray(profile_population_arrays(aged, region="worst", **kw),
-                      np.float32)[:, :4]
-    conv = np.asarray(profile_population_arrays(aged, region="all", **kw),
-                      np.float32)[:, :4]
-    tables = server.state.view("table")[:n_prefix]
-    path = server.state.view("path")[:n_prefix]
-    is_conv = path == PATH_CONVENTIONAL
-    oracle = np.where(is_conv[:, None], conv, diva)
-    ok = (tables == oracle).all(axis=1)
-    return {"n_prefix": int(n_prefix), "n_mismatch": int((~ok).sum()),
-            "parity": bool(ok.all())}
-
-
 def _checkpoint_roundtrip(server) -> bool:
     """save -> load into a fresh server over the same stream; tables,
     labels, paths, counters, and pending deadlines must survive exactly."""
@@ -103,7 +73,7 @@ def bench_serve(n_dimms: int, chunk_size: int, budget_mb: int,
     from repro.core import substrate
     from repro.core.geometry import TINY
     from repro.core.population import synthetic_fleet
-    from repro.serve import FleetConfig, FleetServer
+    from repro.serve import FleetConfig, FleetServer, oracle_parity
 
     if trace_out:
         obs.start_tracing()
@@ -117,7 +87,7 @@ def bench_serve(n_dimms: int, chunk_size: int, budget_mb: int,
     t_ingest = time.perf_counter() - t0
 
     # ---- oracle parity on a dense-profiled prefix (before any aging)
-    parity = _oracle_parity(server, fleet, min(n_dimms, 512))
+    parity = oracle_parity(server, min(n_dimms, 512))
 
     # ---- staleness: walk the fleet clock past every re-profile horizon;
     # after each tick no table may be older than the bound + one tick
@@ -274,6 +244,8 @@ def main() -> None:
     ap.add_argument("--trace-out", default=None,
                     help="record spans; write Chrome trace-event JSON here")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         bench_serve(256, 128, args.budget_mb, args.min_qps, out_path=None,
                     metrics_out=args.metrics_out, trace_out=args.trace_out)

@@ -165,3 +165,20 @@ def synthetic_fleet(n: int, geom: DimmGeometry = SMALL, seed: int = 0):
         )
 
     return PopulationStream(n_dimms=int(n), geom=geom, chunk_fn=chunk_fn)
+
+
+def fleet_dimm_model(batch, i: int) -> DimmModel:
+    """The NumPy-reference ``DimmModel`` of DIMM ``i`` of a
+    ``synthetic_fleet`` chunk: its design template, serial, age and hashed
+    chip/subarray offsets, with no repaired rows (the fleet is pristine).
+    ``profiling.diva_profile_loop`` on it is the per-DIMM reference the
+    batched sweeps must reproduce."""
+    tmpl = fleet_templates(batch.geom)
+    serial = int(batch.serial[i])
+    dimm = DimmModel(batch.geom, tmpl[serial % len(tmpl)], serial=serial,
+                     age_years=float(batch.age_years[i]))
+    dimm.chip_offsets = np.asarray(batch.chip_offsets[i])
+    dimm.sub_offsets = np.asarray(batch.sub_offsets[i])
+    dimm.repaired = np.zeros((batch.geom.subarrays, batch.geom.rows_per_mat),
+                             bool)
+    return dimm

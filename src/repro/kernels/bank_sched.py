@@ -123,12 +123,12 @@ def _make_kernel(statics: dict):
                open_row, ready, pre_ready, bus_ready, last_act, faw_old,
                t_now, tc, bank_rank, bank_chan, *outs):
         res = candidate_times(
-            q_bank[...], q_row[...], q_write[...], q_arrive[...],
-            q_valid[...] != 0, open_row[...], ready[...], pre_ready[...],
-            bus_ready[...], last_act[...], faw_old[...], t_now[0],
-            tc[...], bank_rank[...], bank_chan[...], xp=jnp, **statics)
+            q_bank[0], q_row[0], q_write[0], q_arrive[0], q_valid[0] != 0,
+            open_row[0], ready[0], pre_ready[0], bus_ready[0], last_act[0],
+            faw_old[0], t_now[0, 0], tc[...], bank_rank[0], bank_chan[0],
+            xp=jnp, **statics)
         for o_ref, val in zip(outs, res):
-            o_ref[...] = val
+            o_ref[...] = val[None]
 
     return kernel
 
@@ -139,11 +139,11 @@ def bank_sched(q_bank, q_row, q_write, q_arrive, q_valid,
                open_row, ready, pre_ready, bus_ready, last_act, faw_old,
                t_now, tc, bank_rank, bank_chan, *,
                tbl: int, trrd: int, tfaw: int,
-               use_bus: bool, use_act: bool, q_tile: int | None = None,
-               interpret: bool = True):
+               use_bus: bool, use_act: bool, interpret: bool,
+               q_tile: int | None = None):
     """One scheduler step's candidate scoring as a Pallas call; see
-    ``candidate_times`` for shapes/semantics.  ``t_now`` is passed as a (1,)
-    int32 array.
+    ``candidate_times`` for shapes/semantics.  ``t_now`` is an int32 scalar
+    or (1,) array.
 
     ``q_tile`` tiles the queue axis: the five (Q,) queue slabs and the seven
     (Q,) outputs split into per-tile blocks while the bank/rank/channel state
@@ -157,29 +157,26 @@ def bank_sched(q_bank, q_row, q_write, q_arrive, q_valid,
     q = int(q_bank.shape[0])
     tile = q if q_tile is None else q_tile
     pad = (-q) % tile
-    i32 = lambda v: jnp.asarray(v, jnp.int32)
-    padq = lambda v: jnp.pad(i32(v), (0, pad)) if pad else i32(v)
+    # every vector travels as a (1, n) row: the memsim grid vmaps this call,
+    # which prepends squeezed block dims, and Mosaic accepts the trailing
+    # (1, block) only when the 1 is the array's own leading extent
+    row = lambda v: jnp.asarray(v, jnp.int32).reshape(1, -1)
+    padq = lambda v: jnp.pad(row(v), ((0, 0), (0, pad))) if pad else row(v)
     args = (padq(q_bank), padq(q_row), padq(q_write), padq(q_arrive),
-            padq(jnp.asarray(q_valid).astype(jnp.int32)), i32(open_row),
-            i32(ready), i32(pre_ready), i32(bus_ready), i32(last_act),
-            i32(faw_old), i32(t_now).reshape(1), i32(tc), i32(bank_rank),
-            i32(bank_chan))
+            padq(jnp.asarray(q_valid).astype(jnp.int32)), row(open_row),
+            row(ready), row(pre_ready), row(bus_ready), row(last_act),
+            row(faw_old), row(t_now), jnp.asarray(tc, jnp.int32),
+            row(bank_rank), row(bank_chan))
     qp = q + pad
-    B, Rk = args[5].shape[0], args[9].shape[0]
-    C = args[8].shape[0]
-    q_spec = pl.BlockSpec((tile,), lambda i: (i,))
-    full = lambda *shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
+    q_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
+    full = lambda a: pl.BlockSpec(a.shape, lambda i: (0, 0))
     out = pl.pallas_call(
         _make_kernel(statics),
         grid=(qp // tile,),
-        in_specs=[q_spec, q_spec, q_spec, q_spec, q_spec,
-                  full(B), full(B), full(B), full(C), full(Rk), full(Rk),
-                  full(1), full(B, 6), full(B), full(B)],
+        in_specs=[q_spec] * 5 + [full(a) for a in args[5:]],
         out_specs=[q_spec] * len(OUTPUTS),
-        out_shape=tuple(jax.ShapeDtypeStruct((qp,), jnp.int32)
+        out_shape=tuple(jax.ShapeDtypeStruct((1, qp), jnp.int32)
                         for _ in OUTPUTS),
         interpret=interpret,
     )(*args)
-    if pad:
-        out = tuple(o[:q] for o in out)
-    return out
+    return tuple(o[0, :q] for o in out)

@@ -50,7 +50,7 @@ def _make_kernel(nbits: int, n_rows: int, tile: int):
 
 
 @functools.partial(jax.jit, static_argnames=("nbits", "interpret", "tile"))
-def bit_signature(counts, *, nbits: int, interpret: bool = True,
+def bit_signature(counts, *, nbits: int, interpret: bool,
                   tile: int = TILE_N):
     """counts: (N, R) int32 per-row error counts (R = 2**nbits rows each).
     Returns (N, nbits) int32: per address bit, sum(rows with bit set) -

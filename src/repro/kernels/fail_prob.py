@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.latency import fail_mixture, retention_fail_mixture
 
@@ -77,24 +78,44 @@ def op_cell_probs(rf, colf, even, d_mat, cf, n_rows: int, n_cols: int,
     return p
 
 
-def _make_kernel(block_rows: int, n_cols: int, n_rows_norm: int,
-                 open_bitline: bool):
-    """Kernel over one (block_rows, n_cols) row slab.  Distance normalization
-    always uses the GLOBAL row count ``n_rows_norm`` — rf comes from the
-    row-source VALUES, not the block position, so the per-cell computation is
-    independent of how the row axis is tiled (the tile-invariance contract)."""
-    def kernel(rs_ref, dm_ref, cf_ref, out_ref):
-        rows = rs_ref[...].astype(jnp.float32)            # (block_rows, 1)
-        cf = cf_ref[...]                                  # (1, N_COEFFS)
-        rf = jnp.broadcast_to(rows, (block_rows, n_cols))
-        colf = jax.lax.broadcasted_iota(jnp.float32, (block_rows, n_cols), 1)
-        even = (jax.lax.broadcasted_iota(jnp.int32, (block_rows, n_cols), 1)
-                % 2) == 0
-        p = cell_probs(rf, colf, even, dm_ref[0, 0], cf[0], n_rows_norm,
-                       n_cols, open_bitline)
-        out_ref[...] = p[None]
+def _grid_call(probs, row_src, d_mat, coeffs, n_coeffs: int, cols: int,
+               row_tile: int | None, interpret: bool):
+    """The shared (M, R, C) grid program of both kernels; ``probs(rf, colf,
+    even, d_mat, cf, n_rows)`` is the per-cell formula.
 
-    return kernel
+    One program owns one (mat, row-tile) slab.  The per-mat precharge delay
+    and the folded coefficient row are read as scalars from SMEM (Mosaic
+    refuses a (1, 1) VMEM block over an (M, 1) array), so the row axis is the
+    only blocked input and tiles in multiples of 8.  Distance normalization
+    always uses the GLOBAL row count — rf comes from the row-source VALUES,
+    not the block position, so the per-cell computation is independent of
+    how the row axis is tiled (the tile-invariance contract)."""
+    row_src = jnp.asarray(row_src, jnp.int32).reshape(-1, 1)
+    # (1, n) rows: a vmapped SMEM operand gains a squeezed leading block
+    # dim, and Mosaic accepts the (1, n) trailing block only as the whole
+    # array's trailing dims
+    d_mat = jnp.asarray(d_mat, jnp.float32).reshape(1, -1)
+    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(1, n_coeffs)
+    row_src, R, tile = _row_grid(row_src, row_tile)
+    Rp, M = row_src.shape[0], d_mat.shape[1]
+
+    def kernel(rs_ref, dm_ref, cf_ref, out_ref):
+        rf = jnp.broadcast_to(rs_ref[...].astype(jnp.float32), (tile, cols))
+        col = jax.lax.broadcasted_iota(jnp.int32, (tile, cols), 1)
+        cf = [cf_ref[0, k] for k in range(n_coeffs)]
+        out_ref[...] = probs(rf, col.astype(jnp.float32), (col % 2) == 0,
+                             dm_ref[0, pl.program_id(0)], cf, R)[None]
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        kernel,
+        grid=(M, Rp // tile),
+        in_specs=[pl.BlockSpec((tile, 1), lambda i, j: (j, 0)), smem, smem],
+        out_specs=pl.BlockSpec((1, tile, cols), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((M, Rp, cols), jnp.float32),
+        interpret=interpret,
+    )(row_src, d_mat, coeffs)
+    return out[:, :R]
 
 
 def _row_grid(row_src, row_tile: int | None):
@@ -111,8 +132,8 @@ def _row_grid(row_src, row_tile: int | None):
 
 @functools.partial(jax.jit, static_argnames=("cols", "open_bitline",
                                              "row_tile", "interpret"))
-def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True,
-              row_tile: int | None = None, interpret: bool = True):
+def fail_prob(row_src, d_mat, coeffs, *, cols: int, interpret: bool,
+              open_bitline: bool = True, row_tile: int | None = None):
     """row_src: (R,) int32 repair-resolved internal rows; d_mat: (M,) f32
     precharge-arrival delays; coeffs: (N_COEFFS,) f32 folded coefficient row.
     Returns the (M, R, C) failure-probability grid.
@@ -120,68 +141,28 @@ def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True,
     ``row_tile`` splits the row axis into a second grid dimension (masked
     tail via pad-to-tile + slice-back); per-cell results are bit-identical at
     any tile because each row's computation is independent."""
-    row_src = jnp.asarray(row_src, jnp.int32).reshape(-1, 1)
-    d_mat = jnp.asarray(d_mat, jnp.float32).reshape(-1, 1)
-    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(1, N_COEFFS)
-    row_src, R, tile = _row_grid(row_src, row_tile)
-    Rp, M = row_src.shape[0], d_mat.shape[0]
-    kern = _make_kernel(tile, cols, R, open_bitline)
-    out = pl.pallas_call(
-        kern,
-        grid=(M, Rp // tile),
-        in_specs=[pl.BlockSpec((tile, 1), lambda i, j: (j, 0)),
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-                  pl.BlockSpec((1, N_COEFFS), lambda i, j: (0, 0))],
-        out_specs=pl.BlockSpec((1, tile, cols), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, Rp, cols), jnp.float32),
-        interpret=interpret,
-    )(row_src, d_mat, coeffs)
-    return out[:, :R]
+    def probs(rf, colf, even, dm, cf, n_rows):
+        return cell_probs(rf, colf, even, dm, cf, n_rows, cols, open_bitline)
 
-
-def _make_op_kernel(block_rows: int, n_cols: int, n_rows_norm: int,
-                    open_bitline: bool, voltage: bool, retention: bool):
-    def kernel(rs_ref, dm_ref, cf_ref, out_ref):
-        rows = rs_ref[...].astype(jnp.float32)            # (block_rows, 1)
-        cf = cf_ref[...]                                  # (1, N_OP_COEFFS)
-        rf = jnp.broadcast_to(rows, (block_rows, n_cols))
-        colf = jax.lax.broadcasted_iota(jnp.float32, (block_rows, n_cols), 1)
-        even = (jax.lax.broadcasted_iota(jnp.int32, (block_rows, n_cols), 1)
-                % 2) == 0
-        p = op_cell_probs(rf, colf, even, dm_ref[0, 0], cf[0], n_rows_norm,
-                          n_cols, open_bitline, voltage, retention)
-        out_ref[...] = p[None]
-
-    return kernel
+    return _grid_call(probs, row_src, d_mat, coeffs, N_COEFFS, cols,
+                      row_tile, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("cols", "open_bitline",
                                              "voltage", "retention",
                                              "row_tile", "interpret"))
-def fail_prob_op(row_src, d_mat, coeffs, *, cols: int,
+def fail_prob_op(row_src, d_mat, coeffs, *, cols: int, interpret: bool,
                  open_bitline: bool = True, voltage: bool = False,
-                 retention: bool = False, row_tile: int | None = None,
-                 interpret: bool = True):
+                 retention: bool = False, row_tile: int | None = None):
     """Operating-point variant of ``fail_prob``: coeffs is the
     (N_OP_COEFFS,) f32 row ``[*access 0-8, vdd_shift, ret_base, ret_k,
     ret_x, ret_sigma, ret_drop]``; static ``voltage``/``retention`` gate the
     extra terms (both off => value-identical to ``fail_prob`` on cf[:9]).
     Returns the (M, R, C) summed two-channel probability grid.  ``row_tile``
     tiles the row axis exactly as in ``fail_prob``."""
-    row_src = jnp.asarray(row_src, jnp.int32).reshape(-1, 1)
-    d_mat = jnp.asarray(d_mat, jnp.float32).reshape(-1, 1)
-    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(1, N_OP_COEFFS)
-    row_src, R, tile = _row_grid(row_src, row_tile)
-    Rp, M = row_src.shape[0], d_mat.shape[0]
-    kern = _make_op_kernel(tile, cols, R, open_bitline, voltage, retention)
-    out = pl.pallas_call(
-        kern,
-        grid=(M, Rp // tile),
-        in_specs=[pl.BlockSpec((tile, 1), lambda i, j: (j, 0)),
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-                  pl.BlockSpec((1, N_OP_COEFFS), lambda i, j: (0, 0))],
-        out_specs=pl.BlockSpec((1, tile, cols), lambda i, j: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((M, Rp, cols), jnp.float32),
-        interpret=interpret,
-    )(row_src, d_mat, coeffs)
-    return out[:, :R]
+    def probs(rf, colf, even, dm, cf, n_rows):
+        return op_cell_probs(rf, colf, even, dm, cf, n_rows, cols,
+                             open_bitline, voltage, retention)
+
+    return _grid_call(probs, row_src, d_mat, coeffs, N_OP_COEFFS, cols,
+                      row_tile, interpret)

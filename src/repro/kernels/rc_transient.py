@@ -77,10 +77,11 @@ def _make_kernel(cp: CircuitParams, t_total_ns: float, t_pre_ns: float,
 
 @functools.partial(jax.jit, static_argnames=("cp", "t_total_ns", "t_pre_ns",
                                               "v_ready", "interpret", "tile"))
-def rc_transient(row_frac, col_frac, *, cp: CircuitParams = CircuitParams(),
+def rc_transient(row_frac, col_frac, *, interpret: bool,
+                 cp: CircuitParams = CircuitParams(),
                  t_total_ns: float = 45.0, t_pre_ns: float = 30.0,
                  v_ready: float = 0.9, cell_charged: bool = True,
-                 interpret: bool = True, tile: int = TILE):
+                 tile: int = TILE):
     """row_frac/col_frac: (N,) in [0,1]. Returns dict(v_probe, v_cell, sense_t)."""
     row_frac = jnp.asarray(row_frac, jnp.float32).reshape(-1)
     col_frac = jnp.broadcast_to(jnp.asarray(col_frac, jnp.float32).reshape(-1),

@@ -106,7 +106,9 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
         tile_space=({}, {"tile": 64}, {"tile": 128}, {"tile": 512})),
     KernelSpec(
         "bank_sched", _sched_pallas,
-        tile_space=({}, {"q_tile": 8}, {"q_tile": 16}, {"q_tile": 32})),
+        # rank-1 queue blocks: Mosaic takes the whole axis or a multiple
+        # of 128 lanes
+        tile_space=({}, {"q_tile": 128}, {"q_tile": 256})),
     KernelSpec(
         "diva_shuffle", _shuf_pallas,
         tile_space=({}, {"tile": 64}, {"tile": 128}, {"tile": 512})),

@@ -53,7 +53,7 @@ def _pad_to(x, mult):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile"))
-def encode_checks(data_bits, *, interpret: bool = True, tile: int = TILE_N):
+def encode_checks(data_bits, *, interpret: bool, tile: int = TILE_N):
     """(N, 64) 0/1 int32 -> (N, 8) check bits."""
     x, n = _pad_to(jnp.asarray(data_bits, jnp.int32), tile)
     grid = (x.shape[0] // tile,)
@@ -70,7 +70,7 @@ def encode_checks(data_bits, *, interpret: bool = True, tile: int = TILE_N):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile"))
-def syndrome(code_bits, *, interpret: bool = True, tile: int = TILE_N):
+def syndrome(code_bits, *, interpret: bool, tile: int = TILE_N):
     """(N, 72) 0/1 int32 -> (N, 8) syndrome bits."""
     x, n = _pad_to(jnp.asarray(code_bits, jnp.int32), tile)
     grid = (x.shape[0] // tile,)

@@ -87,8 +87,8 @@ def _perm_matrix(perm_bytes: bytes, inverse: bool) -> np.ndarray:
     return pm.T if inverse else pm
 
 
-def apply_shuffle(bursts, *, inverse: bool = False, shuffle: bool = True,
-                  perm: np.ndarray | None = None, interpret: bool = True,
+def apply_shuffle(bursts, *, interpret: bool, inverse: bool = False,
+                  shuffle: bool = True, perm: np.ndarray | None = None,
                   tile: int = TILE_N):
     """bursts: (N, 576) 0/1 int32 lanes -> permuted (or un-permuted) lanes.
 

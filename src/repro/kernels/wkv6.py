@@ -63,7 +63,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "tile_bh", "chunk"))
-def wkv6(r, k, v, wlog, u, *, interpret: bool = True, tile_bh: int = TILE_BH,
+def wkv6(r, k, v, wlog, u, *, interpret: bool, tile_bh: int = TILE_BH,
          chunk: int = CHUNK):
     """r,k,v,wlog: (B, S, H, dh); u: (H, dh). Returns y (B, S, H, dh).
 

@@ -2,7 +2,8 @@
 the DIMM-fleet timing-table service (``repro.serve.FleetServer``).
 
 ``python -m repro.launch.serve --arch qwen2-0.5b --smoke --tokens 16``
-``python -m repro.launch.serve --fleet 256 --chunk 128 [--ckpt-dir D]``
+``python -m repro.launch.serve --fleet 256 --chunk 128 [--geometry FULL]
+[--ckpt-dir D]``
 
 ``--metrics-out F`` dumps the obs registry (Prometheus text) and
 ``--trace-out F`` records the run as Chrome trace-event JSON — the two
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.data.pipeline import make_batch
 from repro.launch import steps as steps_mod
@@ -54,15 +56,16 @@ def generate(cfg, params, prompt_batch, *, max_new: int = 16):
 
 
 def serve_fleet(n_dimms: int, chunk_size: int,
-                ckpt_dir: str | None = None) -> dict:
-    """Stand up the DIMM-fleet timing-table service over a synthetic fleet:
-    ingest every DIMM, report the serving-path split, optionally checkpoint
-    the state, and return the ingest stats + staleness report."""
-    from repro.core.geometry import TINY
+                ckpt_dir: str | None = None, geometry: str = "TINY") -> dict:
+    """Stand up the DIMM-fleet timing-table service over a synthetic fleet
+    of the named ``core.geometry`` preset: ingest every DIMM, report the
+    serving-path split, optionally checkpoint the state, and return the
+    ingest stats + staleness report."""
+    from repro.core import geometry as geo
     from repro.core.population import synthetic_fleet
     from repro.serve import FleetConfig, FleetServer
 
-    fleet = synthetic_fleet(n_dimms, TINY, seed=0)
+    fleet = synthetic_fleet(n_dimms, getattr(geo, geometry), seed=0)
     server = FleetServer(fleet, FleetConfig(chunk_size=chunk_size),
                          checkpoint_dir=ckpt_dir)
     with obs.span("serve.fleet_ingest", n_dimms=n_dimms) as sp:
@@ -92,6 +95,9 @@ def main(argv=None) -> dict:
                     help="serve a DIMM fleet of this size instead of an LLM")
     ap.add_argument("--chunk", type=int, default=128,
                     help="fleet ingest chunk size (with --fleet)")
+    ap.add_argument("--geometry", default="TINY",
+                    choices=("TINY", "SMALL", "FULL"),
+                    help="DIMM geometry preset of the fleet (with --fleet)")
     ap.add_argument("--ckpt-dir", default=None,
                     help="checkpoint directory (with --fleet)")
     ap.add_argument("--metrics-out", default=None,
@@ -100,11 +106,13 @@ def main(argv=None) -> dict:
                     help="record spans; write Chrome trace-event JSON here")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.trace_out:
         obs.start_tracing()
     try:
         if args.fleet:
-            stats = serve_fleet(args.fleet, args.chunk, args.ckpt_dir)
+            stats = serve_fleet(args.fleet, args.chunk, args.ckpt_dir,
+                                args.geometry)
         else:
             cfg = get_smoke_config(args.arch) if args.smoke \
                 else get_config(args.arch)

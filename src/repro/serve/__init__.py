@@ -2,6 +2,6 @@
 DIMM fleet (signature-cache hits, discovery on miss, staleness-driven
 re-profiling, checkpointed state)."""
 from repro.serve.server import (FleetConfig, FleetServer, concat_batches,
-                                take_batch)
+                                oracle_parity, take_batch)
 from repro.serve.state import (PATH_CONVENTIONAL, PATH_DISCOVER, PATH_HIT,
                                FleetState, GenerationCache)

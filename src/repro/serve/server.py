@@ -87,6 +87,35 @@ _M_REPROF = _OBS_REGISTRY.counter(
     labelnames=("server",))
 
 
+def oracle_parity(server: "FleetServer", n_prefix: int) -> dict:
+    """Compare the first ``n_prefix`` served tables against the dense oracle
+    for their path: hit/discover vs ``diva_profile``'s sweep
+    (region="worst"), conventional vs the every-row sweep (region="all").
+    Each DIMM is profiled at the fleet age its table was served at
+    (``profiled_at``), so the check holds after re-profiling ticks too.
+    Tables must match bit for bit."""
+    from repro.core.substrate import profile_population_arrays
+    view = server.state.view
+    n_prefix = min(int(n_prefix), len(view("serial")))
+    batch = server.stream.chunk(0, n_prefix)
+    aged = dataclasses.replace(
+        batch, age_years=np.asarray(view("profiled_at")[:n_prefix],
+                                    np.float32))
+    kw = dict(temp_C=server.cfg.profile_temp_C,
+              refresh_ms=server.cfg.profile_refresh_ms,
+              guard_cycles=server.cfg.guard_cycles,
+              multibit_only=server.cfg.multibit_only)
+    diva = np.asarray(profile_population_arrays(aged, region="worst", **kw),
+                      np.float32)[:, :4]
+    conv = np.asarray(profile_population_arrays(aged, region="all", **kw),
+                      np.float32)[:, :4]
+    is_conv = view("path")[:n_prefix] == PATH_CONVENTIONAL
+    oracle = np.where(is_conv[:, None], conv, diva)
+    ok = (view("table")[:n_prefix] == oracle).all(axis=1)
+    return {"n_prefix": n_prefix, "n_mismatch": int((~ok).sum()),
+            "parity": bool(ok.all())}
+
+
 def take_batch(batch, idx):
     """Arbitrary-index population subset (the fancy-index sibling of
     ``streaming.slice_batch``)."""

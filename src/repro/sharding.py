@@ -18,34 +18,21 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-# ------------------------------------------------- jax-version compatibility
+# ------------------------------------------------------- mesh construction
 
 def mesh_axis_types_kw(n_axes: int) -> dict:
-    """`make_mesh(axis_types=...)` kwarg, or {} on jax < 0.5 (where
-    sharding.AxisType does not exist and Auto is the only behaviour)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
+    """`make_mesh(axis_types=...)` kwarg: every axis Auto."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def abstract_mesh(shape: tuple, names: tuple):
-    """AbstractMesh across jax versions: >= 0.5 takes (shape, names), 0.4.x
-    takes a tuple of (name, size) pairs."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
+    return jax.sharding.AbstractMesh(shape, names)
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, falling back to the
-    jax.experimental spelling (check_rep) on jax < 0.5."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """jax.shard_map with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 # ------------------------------------------------ population (DIMM-axis) mesh
 

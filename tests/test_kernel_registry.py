@@ -230,3 +230,34 @@ def test_bucket_pow2():
 
 def test_load_cache_missing_file_is_zero(tmp_path):
     assert tune.load_cache(tmp_path / "absent.json") == 0
+
+
+# ------------------------------------------------- no hidden interpret route
+
+def _tiny_call(name):
+    """(args, kwargs minus ``interpret``) of a small call to ``name``."""
+    i32 = lambda *shape: np.zeros(shape, np.int32)
+    f32 = lambda *shape: np.zeros(shape, np.float32)
+    if name in ("fail_prob", "fail_prob_op"):
+        return (i32(8), f32(2), f32(9 if name == "fail_prob" else 15)), \
+            dict(cols=8)
+    if name == "bank_sched":
+        q, b, r = i32(8), i32(4), i32(2)
+        return ((q, q, q, q, q, b, b, b, r, r, r, i32(1), i32(4, 6), b, b),
+                dict(tbl=4, trrd=5, tfaw=24, use_bus=True, use_act=True))
+    return {"secded_encode": ((i32(8, 64),), {}),
+            "secded_syndrome": ((i32(8, 72),), {}),
+            "bit_signature": ((i32(8, 8),), dict(nbits=3)),
+            "diva_shuffle": ((i32(8, 576),), {}),
+            "rc_transient": ((f32(8), f32(8)), {}),
+            "wkv6": ((f32(1, 8, 1, 4),) * 4 + (f32(1, 4),), {})}[name]
+
+
+@pytest.mark.parametrize("name", KERNEL_NAMES)
+def test_pallas_impl_requires_explicit_interpret(name):
+    """Only the dispatcher chooses interpret-vs-compiled: an implementation
+    called without ``interpret=`` is an error, never a silent interpreter
+    run on an accelerator."""
+    args, kw = _tiny_call(name)
+    with pytest.raises(TypeError, match="interpret"):
+        REGISTRY[name].pallas(*args, **kw)

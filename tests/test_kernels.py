@@ -14,14 +14,14 @@ RNG = np.random.default_rng(42)
 @pytest.mark.parametrize("n", [1, 7, 512, 1000, 2049])
 def test_secded_encode_shapes(n):
     data = RNG.integers(0, 2, (n, 64)).astype(np.int32)
-    np.testing.assert_array_equal(np.asarray(encode_checks(data)),
+    np.testing.assert_array_equal(np.asarray(encode_checks(data, interpret=True)),
                                   np.asarray(ref.secded_encode(data)))
 
 
 @pytest.mark.parametrize("n", [3, 256, 777])
 def test_secded_syndrome_shapes(n):
     code = RNG.integers(0, 2, (n, 72)).astype(np.int32)
-    np.testing.assert_array_equal(np.asarray(syndrome(code)),
+    np.testing.assert_array_equal(np.asarray(syndrome(code, interpret=True)),
                                   np.asarray(ref.secded_syndrome(code)))
 
 
@@ -29,14 +29,16 @@ def test_secded_syndrome_shapes(n):
 @pytest.mark.parametrize("inverse", [False, True])
 def test_shuffle_kernel(n, inverse):
     b = RNG.integers(0, 2, (n, 576)).astype(np.int32)
-    np.testing.assert_array_equal(np.asarray(apply_shuffle(b, inverse=inverse)),
+    np.testing.assert_array_equal(np.asarray(apply_shuffle(b, inverse=inverse,
+                                                        interpret=True)),
                                   np.asarray(ref.diva_shuffle(b, inverse)))
 
 
 def test_shuffle_roundtrip():
     b = RNG.integers(0, 2, (50, 576)).astype(np.int32)
     np.testing.assert_array_equal(
-        np.asarray(apply_shuffle(apply_shuffle(b), inverse=True)), b)
+        np.asarray(apply_shuffle(apply_shuffle(b, interpret=True),
+                                 inverse=True, interpret=True)), b)
 
 
 @pytest.mark.parametrize("n", [4, 130])
@@ -112,17 +114,19 @@ def test_shuffle_and_signature_tile_invariant_exact(tile):
         np.asarray(ref.bit_signature(counts, 9)))
 
 
-@pytest.mark.parametrize("q_tile", [None, 3, 8, 16])
+@pytest.mark.parametrize("q_tile", [None, 128, 256])
 def test_bank_sched_tile_invariant_exact(q_tile):
     """Queue tiling pads with q_valid=0 slots (arbitration key 0, sliced
     off); per-candidate scoring is independent, so all-int outputs are
-    exact at any q_tile, dividing or not."""
+    exact at any q_tile, dividing or not (300 slots: three 128-blocks with
+    a ragged tail, or one padded 256-block and a ragged second)."""
     rng = np.random.default_rng(11)
-    args = (rng.integers(0, 16, 10).astype(np.int32),
-            rng.integers(0, 50, 10).astype(np.int32),
-            rng.integers(0, 2, 10).astype(np.int32),
-            rng.integers(0, 400, 10).astype(np.int32),
-            np.array([1, 1, 0, 1, 1, 1, 0, 1, 1, 1], np.int32),
+    q = 300
+    args = (rng.integers(0, 16, q).astype(np.int32),
+            rng.integers(0, 50, q).astype(np.int32),
+            rng.integers(0, 2, q).astype(np.int32),
+            rng.integers(0, 400, q).astype(np.int32),
+            (rng.random(q) < 0.8).astype(np.int32),
             rng.integers(-1, 50, 16).astype(np.int32),
             rng.integers(0, 500, 16).astype(np.int32),
             rng.integers(-100, 500, 16).astype(np.int32),

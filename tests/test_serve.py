@@ -18,7 +18,7 @@ from repro.core.geometry import TINY
 from repro.core.population import synthetic_fleet
 from repro.core.substrate import profile_population_arrays
 from repro.serve import (PATH_CONVENTIONAL, PATH_DISCOVER, PATH_HIT,
-                         FleetConfig, FleetServer)
+                         FleetConfig, FleetServer, oracle_parity)
 from repro.serve.state import FleetState, GenerationCache
 
 N, CHUNK = 128, 64
@@ -178,6 +178,19 @@ def test_tick_reprofiles_due_dimms_to_aged_oracle():
     oracle = np.where(is_conv[:, None], conv, diva)
     np.testing.assert_array_equal(server.state.view("table")[was_due],
                                   oracle[was_due])
+    # the library check ages each DIMM to its own profiled_at: the mixed
+    # fleet (re-profiled at `now`, the rest at 0) is exact as a whole
+    assert oracle_parity(server, n) == {"n_prefix": n, "n_mismatch": 0,
+                                        "parity": True}
+
+
+def test_launch_serve_fleet_takes_geometry_by_name():
+    """``launch/serve.py --fleet --geometry NAME`` serves the named preset;
+    TINY is the CI default."""
+    from repro.launch.serve import serve_fleet
+    stats = serve_fleet(64, 32, geometry="TINY")
+    assert stats["ingested"] == 64
+    assert stats["hits"] + stats["misses"] + stats["conventional"] == 64
 
 
 # -------------------------------------------------------------- checkpointing

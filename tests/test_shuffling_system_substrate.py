@@ -115,8 +115,8 @@ def test_apply_shuffle_inverse_roundtrip_is_identity():
     rng = np.random.default_rng(0)
     b = rng.integers(0, 2, (40, 576)).astype(np.int32)
     for shuffle in (True, False):
-        out = apply_shuffle(apply_shuffle(b, shuffle=shuffle),
-                            inverse=True, shuffle=shuffle)
+        out = apply_shuffle(apply_shuffle(b, shuffle=shuffle, interpret=True),
+                            inverse=True, shuffle=shuffle, interpret=True)
         np.testing.assert_array_equal(np.asarray(out), b)
 
 
@@ -127,7 +127,7 @@ def test_apply_shuffle_matches_beat_of_bit_lane_for_lane(shuffle):
     from repro.kernels.shuffle import apply_shuffle
     rng = np.random.default_rng(1)
     b = rng.integers(0, 2, (8, 576)).astype(np.int32)
-    out = np.asarray(apply_shuffle(b, shuffle=shuffle))
+    out = np.asarray(apply_shuffle(b, shuffle=shuffle, interpret=True))
     for chip in range(9):
         for bit in range(64):
             beat = int(shuffling.beat_of_bit(bit, chip, shuffle and chip < 8))
@@ -144,8 +144,8 @@ def test_assemble_error_masks_matches_kernel_layout(shuffle):
     rng = np.random.default_rng(2)
     e = (rng.random((9, 64)) < 0.05).astype(np.int32)
     masks = shuffling.assemble_error_masks(e, shuffle=shuffle)
-    kern = np.asarray(apply_shuffle(e.reshape(1, 576),
-                                    shuffle=shuffle)).reshape(8, 72)
+    kern = np.asarray(apply_shuffle(e.reshape(1, 576), shuffle=shuffle,
+                                    interpret=True)).reshape(8, 72)
     np.testing.assert_array_equal(masks, kern)
 
 

@@ -136,6 +136,22 @@ def test_synthetic_fleet_chunks_are_position_invariant():
                                       err_msg=leaf)
 
 
+def test_fleet_dimm_model_matches_the_batched_sweep():
+    """The NumPy walker on ``fleet_dimm_model`` reproduces the jitted
+    population sweep table for table — the per-DIMM reference behind the
+    chip smoke's check of served fleets."""
+    from repro.core.population import fleet_dimm_model
+    from repro.core.profiling import diva_profile_loop
+    batch = FLEET.chunk(0, 3)
+    dense = substrate.profile_population_arrays(
+        batch, region="worst", temp_C=55.0, multibit_only=True)
+    for i in range(3):
+        tp = diva_profile_loop(fleet_dimm_model(batch, i), temp_C=55.0)
+        np.testing.assert_array_equal(
+            np.asarray([tp.trcd, tp.tras, tp.trp, tp.twr], np.float32),
+            dense[i])
+
+
 def test_stream_wrappers():
     s = st.as_stream(BATCH)
     assert isinstance(s, st.PopulationStream)
