@@ -34,6 +34,7 @@ any gate failure:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import tempfile
@@ -75,8 +76,9 @@ def bench_serve(n_dimms: int, chunk_size: int, budget_mb: int,
     from repro.core.population import synthetic_fleet
     from repro.serve import FleetConfig, FleetServer, oracle_parity
 
+    recording = contextlib.ExitStack()
     if trace_out:
-        obs.start_tracing()
+        recording.enter_context(obs.record(trace_out))
 
     fleet = synthetic_fleet(n_dimms, TINY, seed=0)
     server = FleetServer(fleet, FleetConfig(chunk_size=chunk_size))
@@ -213,8 +215,8 @@ def bench_serve(n_dimms: int, chunk_size: int, budget_mb: int,
         failures.append(f"peak RSS {peak_mb:.0f} MB exceeds the "
                         f"{budget_mb} MB budget")
     if trace_out:
-        obs.stop_tracing()
-        print(f"trace  -> {obs.write_chrome_trace(trace_out)}")
+        recording.close()
+        print(f"trace  -> {trace_out}")
     if metrics_out:
         with open(metrics_out, "w") as f:
             f.write(obs.REGISTRY.prometheus_text())
@@ -242,7 +244,8 @@ def main() -> None:
     ap.add_argument("--metrics-out", default=None,
                     help="write the obs registry as Prometheus text here")
     ap.add_argument("--trace-out", default=None,
-                    help="record spans; write Chrome trace-event JSON here")
+                    help="record a profiler trace (spans and device "
+                         "events); write Chrome trace-event JSON here")
     args = ap.parse_args()
     from repro.compile_cache import enable_compile_cache
     enable_compile_cache()

@@ -61,7 +61,6 @@ from repro.core.substrate import (DimmBatch, _LEAVES, _axis_context,
                                   pattern_stress)
 from repro.core.timing import PARAMS, VDD_STD
 from repro.obs import REGISTRY as _OBS_REGISTRY
-from repro.obs import tracing as _obs_tracing
 from repro.sharding import chunk_spans
 
 # chunk outputs rarely share a (shape, dtype) with the donated chunk leaves;
@@ -73,8 +72,8 @@ warnings.filterwarnings(
 
 # Streaming throughput accounting (obs layer, ARCHITECTURE 3h).  Chunk
 # dispatches and folded DIMMs are counted at the HOST chunk boundary — the
-# clock the DIMMs/s ROADMAP gate ticks against.  Per-chunk spans are guarded
-# on ``tracing.active()`` so an idle tracer costs the hot loop nothing.
+# clock the DIMMs/s ROADMAP gate ticks against.  A chunk's execution shows
+# in a profiler trace as its program's own device event.
 _OBS_CHUNKS = _OBS_REGISTRY.counter(
     "repro_stream_chunks_total",
     "chunk programs dispatched by the streaming driver, by entry point",
@@ -336,19 +335,8 @@ def _chunk_call(name: str, impl, args, statics: dict, donate: tuple,
     """One chunk dispatch: the donated cached jit, or the sharded route when
     a mesh is given (shard_map has its own program cache; donation does not
     compose with it and is skipped).  Also the streaming layer's one
-    instrumentation point: a chunk counter always, a "stream.chunk" span
-    only while a trace is recording (the ``active()`` guard keeps the hot
-    loop at one branch otherwise)."""
+    instrumentation point: the chunk counter."""
     _OBS_CHUNKS.labels(entry=name).inc()
-    if _obs_tracing.active():
-        with _obs_tracing.span("stream.chunk", entry=name) as sp:
-            if mesh is None:
-                out = _chunk_jitted(name, impl, statics, donate)(*args)
-            else:
-                out = _run_sharded(name, mesh, impl, args, statics,
-                                   batch_argnums)
-            sp.bind(out)
-        return out
     if mesh is None:
         return _chunk_jitted(name, impl, statics, donate)(*args)
     return _run_sharded(name, mesh, impl, args, statics, batch_argnums)

@@ -6,12 +6,15 @@ the DIMM-fleet timing-table service (``repro.serve.FleetServer``).
 [--ckpt-dir D]``
 
 ``--metrics-out F`` dumps the obs registry (Prometheus text) and
-``--trace-out F`` records the run as Chrome trace-event JSON — the two
-observability artifacts CI uploads per leg.
+``--trace-out F`` records the run under the JAX profiler and writes it as
+Chrome trace-event JSON: the host spans (``serve.*`` phases) and the
+device's programs and ops on one clock — the two observability artifacts
+CI uploads per leg.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -103,36 +106,37 @@ def main(argv=None) -> dict:
     ap.add_argument("--metrics-out", default=None,
                     help="write the obs registry as Prometheus text here")
     ap.add_argument("--trace-out", default=None,
-                    help="record spans; write Chrome trace-event JSON here")
+                    help="record a profiler trace (spans and device "
+                         "events); write Chrome trace-event JSON here")
     args = ap.parse_args(argv)
 
     enable_compile_cache()
-    if args.trace_out:
-        obs.start_tracing()
+    recording = (obs.record(args.trace_out) if args.trace_out
+                 else contextlib.nullcontext())
     try:
-        if args.fleet:
-            stats = serve_fleet(args.fleet, args.chunk, args.ckpt_dir,
-                                args.geometry)
-        else:
-            cfg = get_smoke_config(args.arch) if args.smoke \
-                else get_config(args.arch)
-            params = model_mod.init_params(jax.random.PRNGKey(0), cfg)
-            batch = make_batch(cfg, args.batch, args.prompt_len,
-                               seed=0, step=0)
-            batch["tokens"] = batch["tokens"][:, :-1]
+        with recording:
+            if args.fleet:
+                stats = serve_fleet(args.fleet, args.chunk, args.ckpt_dir,
+                                    args.geometry)
+            else:
+                cfg = get_smoke_config(args.arch) if args.smoke \
+                    else get_config(args.arch)
+                params = model_mod.init_params(jax.random.PRNGKey(0), cfg)
+                batch = make_batch(cfg, args.batch, args.prompt_len,
+                                   seed=0, step=0)
+                batch["tokens"] = batch["tokens"][:, :-1]
 
-            with make_host_mesh():
-                toks, stats = generate(cfg, params, batch,
-                                       max_new=args.tokens)
-            print(f"{args.arch}: generated {toks.shape} "
-                  f"prefill={stats['prefill_s']:.2f}s "
-                  f"decode={stats['decode_s']:.2f}s "
-                  f"({stats['tok_per_s']:.1f} tok/s)")
-            assert np.isfinite(np.asarray(toks)).all()
+                with make_host_mesh():
+                    toks, stats = generate(cfg, params, batch,
+                                           max_new=args.tokens)
+                print(f"{args.arch}: generated {toks.shape} "
+                      f"prefill={stats['prefill_s']:.2f}s "
+                      f"decode={stats['decode_s']:.2f}s "
+                      f"({stats['tok_per_s']:.1f} tok/s)")
+                assert np.isfinite(np.asarray(toks)).all()
     finally:
         if args.trace_out:
-            obs.stop_tracing()
-            print(f"trace  -> {obs.write_chrome_trace(args.trace_out)}")
+            print(f"trace  -> {args.trace_out}")
         if args.metrics_out:
             with open(args.metrics_out, "w") as f:
                 f.write(obs.REGISTRY.prometheus_text())

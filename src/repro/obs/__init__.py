@@ -1,7 +1,7 @@
 """Fleet observability: metrics registry + span tracing (ARCHITECTURE 3h).
 
-One process-global ``REGISTRY`` of counters/gauges/histograms and a span
-tracer emitting Chrome trace-event JSON, threaded through every hot layer —
+One process-global ``REGISTRY`` of counters/gauges/histograms and spans
+written into the JAX profiler's trace, threaded through every hot layer —
 substrate compile caches, kernel dispatch, streaming chunk scans, the
 FleetServer serving paths, checkpointing, and the launch drivers.
 
@@ -18,14 +18,14 @@ and disabled mode costs one branch per event.
         ...
     print(obs.REGISTRY.prometheus_text())
 
-``obs.disable()`` / ``obs.enable()`` flip the metrics registry;
-``obs.start_tracing()`` / ``obs.stop_tracing()`` scope a trace recording.
+``obs.disable()`` / ``obs.enable()`` flip the metrics registry; spans
+reach a trace while a profiler session records (``jax.profiler.trace``,
+or ``obs.record(path)``, which writes the session as Chrome trace-event
+JSON).
 """
 from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
                                Metric, REGISTRY, Registry)
-from repro.obs.tracing import (Span, active, chrome_trace, span,
-                               start_tracing, stop_tracing, trace_events,
-                               write_chrome_trace)
+from repro.obs.tracing import Span, record, span
 
 
 def enable() -> None:
@@ -34,7 +34,7 @@ def enable() -> None:
 
 def disable() -> None:
     """Freeze every metric (reads still work, events become one branch).
-    Tracing is separately scoped by ``start_tracing``/``stop_tracing``."""
+    Spans still reach a recording profiler session."""
     REGISTRY.enabled = False
 
 
@@ -65,7 +65,6 @@ def peak_rss_mb() -> float:
 
 __all__ = [
     "DEFAULT_BUCKETS", "Counter", "Gauge", "Histogram", "Metric",
-    "REGISTRY", "Registry", "Span", "active", "chrome_trace", "disable",
-    "enable", "enabled", "peak_rss_mb", "span", "start_tracing",
-    "stop_tracing", "trace_events", "write_chrome_trace",
+    "REGISTRY", "Registry", "Span", "disable", "enable", "enabled",
+    "peak_rss_mb", "record", "span",
 ]

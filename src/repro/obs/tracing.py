@@ -1,76 +1,59 @@
-"""Host-side span tracing, exported as Chrome trace-event JSON (Perfetto).
+"""Host-side spans, written into the JAX profiler's trace.
 
-Spans are plain context managers around host code: ``perf_counter`` at entry
-and exit, an optional ``jax.block_until_ready`` on a bound device value at
-close (so a device-bound span measures compute, not dispatch — the
-``launch/serve`` stopwatch rule), and an optional ``Histogram`` the duration
-is observed into.  Collection into the trace buffer happens only while a
-trace is being recorded (``start_tracing``/``stop_tracing``); outside a
-recording, a span is two clock reads and a branch.
+A span is a context manager around host code.  On entry it opens a
+``jax.profiler.TraceAnnotation`` of its name; on exit it waits for an
+optional bound device value (``jax.block_until_ready``, so a device-bound
+span covers the compute and not only the dispatch), closes the annotation
+and observes its ``perf_counter`` duration into an optional ``Histogram``.
+The annotation closes after the wait, so the profiler's event covers it.
+
+While a profiler session records (``jax.profiler.trace`` / ``start_trace``)
+each span is an event of the host plane, on the same clock as the device's
+ops and programs, under exactly its name; its keyword args become the
+event's metadata (stats), never part of its name.  With no session
+recording, the annotation is one native no-op and a span is two clock
+reads.
 
 Spans live strictly at HOST boundaries — around jitted calls, never inside
 them (a span inside traced code would run at trace time and measure
 nothing).  Because a span only reads clocks and blocks on already-scheduled
-work, enabling tracing cannot change any computed value or add any compile:
-the bit-parity + no-retrace contract, asserted in tests/test_obs.py.
+work, recording cannot change any computed value or add any compile: the
+bit-parity + no-retrace contract, asserted in tests/test_obs.py.
 
-    from repro.obs import span, start_tracing, write_chrome_trace
-    start_tracing()
-    with span("serve.ingest", n=256) as sp:
-        out = server.ingest()
-        sp.bind(out)                 # block on it at span close
-    write_chrome_trace("trace.json")
+Naming: a child span's name starts with its parent's (``serve.ingest`` >
+``serve.ingest.campaign``), so a reader of the trace can tell the phase
+from the name alone and the innermost phase sorts last among equals.
 
-The emitted file is the Chrome trace-event format: a JSON object with a
-``traceEvents`` list of complete ("ph": "X") events in microseconds —
-loadable as-is in Perfetto / chrome://tracing.
+    from repro import obs
+    with obs.record("trace.json"):
+        with obs.span("serve.ingest", n=256) as sp:
+            out = server.ingest()
+            sp.bind(out)                 # block on it at span close
+
+``record`` writes the profiler's Perfetto export: Chrome trace-event JSON
+(a ``traceEvents`` list in microseconds) holding the spans and the
+device's events, loadable as-is in Perfetto / chrome://tracing.
 """
 from __future__ import annotations
 
-import json
-import os
-import threading
+import contextlib
+import gzip
+import shutil
+import tempfile
 import time
+from pathlib import Path
 
-_lock = threading.Lock()
-_active = False
-_events: list[dict] = []
-_t_epoch = time.perf_counter()      # trace timestamps are relative to import
-
-
-def active() -> bool:
-    """True while a trace is being recorded — hot loops may guard optional
-    per-iteration spans on this to skip even the clock reads."""
-    return _active
-
-
-def start_tracing() -> None:
-    """Begin recording span events (clears any previous buffer)."""
-    global _active
-    with _lock:
-        _events.clear()
-        _active = True
-
-
-def stop_tracing() -> list[dict]:
-    """Stop recording; returns (and keeps) the collected events."""
-    global _active
-    with _lock:
-        _active = False
-        return list(_events)
-
-
-def trace_events() -> list[dict]:
-    return list(_events)
+import jax
 
 
 class Span:
     """One timed section.  ``bind(value)`` registers a jax pytree to
-    ``block_until_ready`` at exit; ``set(**kv)`` attaches trace args;
+    ``block_until_ready`` at exit; ``set(**kv)`` attaches trace metadata;
     ``duration_s`` is readable after exit (the stats the launch/bench
     drivers report — one code path for timings and traces)."""
 
-    __slots__ = ("name", "args", "hist", "_bound", "_t0", "duration_s")
+    __slots__ = ("name", "args", "hist", "_bound", "_t0", "_note",
+                 "duration_s")
 
     def __init__(self, name: str, hist=None, **args):
         self.name = name
@@ -78,6 +61,7 @@ class Span:
         self.hist = hist
         self._bound = None
         self._t0 = 0.0
+        self._note = None
         self.duration_s = 0.0
 
     def bind(self, value) -> "Span":
@@ -86,29 +70,25 @@ class Span:
 
     def set(self, **kv) -> "Span":
         self.args.update(kv)
+        if self._note is not None:
+            self._note.set_metadata(**kv)
         return self
 
     def __enter__(self) -> "Span":
+        self._note = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         if self._bound is not None:
-            import jax
             jax.block_until_ready(self._bound)
             self._bound = None
-        t1 = time.perf_counter()
-        self.duration_s = t1 - self._t0
+        self.duration_s = time.perf_counter() - self._t0
+        self._note.__exit__(exc_type, exc, tb)
+        self._note = None
         if self.hist is not None:
             self.hist.observe(self.duration_s)
-        if _active:
-            with _lock:
-                _events.append({
-                    "name": self.name, "ph": "X", "cat": "repro",
-                    "pid": os.getpid(), "tid": threading.get_ident() & 0xffff,
-                    "ts": (self._t0 - _t_epoch) * 1e6,
-                    "dur": self.duration_s * 1e6,
-                    "args": self.args})
 
 
 def span(name: str, hist=None, **args) -> Span:
@@ -116,14 +96,19 @@ def span(name: str, hist=None, **args) -> Span:
     return Span(name, hist=hist, **args)
 
 
-def chrome_trace() -> dict:
-    """The Chrome trace-event JSON object for the collected events."""
-    return {"traceEvents": trace_events(), "displayTimeUnit": "ms"}
-
-
-def write_chrome_trace(path) -> str:
-    """Write the collected events as Chrome trace-event JSON; returns the
-    path (str) for log lines."""
-    with open(path, "w") as f:
-        json.dump(chrome_trace(), f)
-    return str(path)
+@contextlib.contextmanager
+def record(path):
+    """Record a profiler trace of the enclosed code (host spans and device
+    events, no Python function tracer) and write its Chrome trace-event
+    JSON to ``path``, also when the code raises."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with tempfile.TemporaryDirectory() as tmp:
+        try:
+            with jax.profiler.trace(tmp, create_perfetto_trace=True,
+                                    profiler_options=opts):
+                yield
+        finally:                      # a failed run's trace is kept too
+            for found in sorted(Path(tmp).rglob("perfetto_trace.json.gz")):
+                with gzip.open(found, "rb") as src, open(path, "wb") as dst:
+                    shutil.copyfileobj(src, dst)

@@ -85,6 +85,13 @@ _M_GENS = _OBS_REGISTRY.gauge(
 _M_REPROF = _OBS_REGISTRY.counter(
     "repro_serve_reprofiled_total", "DIMMs re-profiled by tick()",
     labelnames=("server",))
+# One span per phase of an ingest chunk or a tick (never per DIMM); a child
+# phase's name starts with its parent's, and every phase span observes its
+# wall time here, so per-phase tails survive untraced runs.
+_M_PHASE = _OBS_REGISTRY.histogram(
+    "repro_serve_phase_seconds",
+    "wall time of one FleetServer phase (the span of that name)",
+    labelnames=("server", "phase"))
 
 
 def oracle_parity(server: "FleetServer", n_prefix: int) -> dict:
@@ -201,6 +208,16 @@ class FleetServer:
         self._m_age = _M_AGE.labels(server=self._sid)
         self._m_gens = _M_GENS.labels(server=self._sid)
         self._m_reprof = _M_REPROF.labels(server=self._sid)
+        self._m_phase: dict = {}
+
+    def _phase(self, name: str, **args):
+        """The span of one serving phase, observed into
+        ``repro_serve_phase_seconds{server, phase=name}``."""
+        hist = self._m_phase.get(name)
+        if hist is None:
+            hist = self._m_phase[name] = _M_PHASE.labels(server=self._sid,
+                                                         phase=name)
+        return _span(name, hist, **args)
 
     # ------------------------------------------------------------- ingest
 
@@ -215,7 +232,7 @@ class FleetServer:
         before = (self.cache.hits, self.cache.misses, self.cache.conventional)
         for lo in range(lo0, hi0, self._full):
             hi = min(lo + self._full, hi0)
-            with _span("serve.ingest_chunk", server=self._sid, lo=lo, hi=hi):
+            with self._phase("serve.ingest", server=self._sid, lo=lo, hi=hi):
                 self._ingest_chunk(self.stream.chunk(lo, hi), now)
             self._ingested = hi
         self.clock = max(self.clock, now)
@@ -228,40 +245,41 @@ class FleetServer:
     def _ingest_chunk(self, batch, now: float) -> None:
         cfg = self.cfg
         n = batch.n_dimms
-        g = batch.geom
-        S, R = g.subarrays, g.rows_per_mat
-        padded = pad_batch(batch, self._full - n)
 
         # campaign telemetry: serial-keyed counts at every operating point
         # (one compiled program; t_op is data, not a static)
-        counts_t = np.stack([
-            hash_poisson_counts(padded, cfg.campaign_param, float(t),
-                                temp_C=cfg.campaign_temp_C,
-                                refresh_ms=cfg.campaign_refresh_ms,
-                                seed=cfg.campaign_seed)[:n]
-            for t in cfg.campaign_t_ops])                  # (T, n, S, R)
+        with self._phase("serve.ingest.campaign"):
+            padded = pad_batch(batch, self._full - n)
+            counts_t = np.stack([
+                hash_poisson_counts(padded, cfg.campaign_param, float(t),
+                                    temp_C=cfg.campaign_temp_C,
+                                    refresh_ms=cfg.campaign_refresh_ms,
+                                    seed=cfg.campaign_seed)[:n]
+                for t in cfg.campaign_t_ops])              # (T, n, S, R)
         T = counts_t.shape[0]
 
         # per-DIMM onset point + onset-block signature features (the
         # BlindDiva matching key: DIMMs with different onsets are different
         # designs and land in disjoint feature blocks)
-        max_t = np.stack([np.median(counts_t[t].max(axis=2), axis=1)
-                          for t in range(T)])              # (T, n)
-        onset = np.full(n, T - 1, np.int64)
-        for d in range(n):
-            hit = np.flatnonzero(max_t[:, d] >= cfg.onset_min_count)
-            if hit.size:
-                onset[d] = int(hit[0])
-        feats_t = [signature_features(
-            bit_signature_population(counts_t[t].astype(np.int32)))
-            for t in range(T)]                             # T x (n, nbits)
-        nb = self._nbits
-        feats = np.zeros((n, T * nb))
-        for d in range(n):
-            t = onset[d]
-            feats[d, t * nb:(t + 1) * nb] = feats_t[t][d]
+        with self._phase("serve.ingest.signature"):
+            max_t = np.stack([np.median(counts_t[t].max(axis=2), axis=1)
+                              for t in range(T)])          # (T, n)
+            onset = np.full(n, T - 1, np.int64)
+            for d in range(n):
+                hit = np.flatnonzero(max_t[:, d] >= cfg.onset_min_count)
+                if hit.size:
+                    onset[d] = int(hit[0])
+            feats_t = [signature_features(
+                bit_signature_population(counts_t[t].astype(np.int32)))
+                for t in range(T)]                         # T x (n, nbits)
+            nb = self._nbits
+            feats = np.zeros((n, T * nb))
+            for d in range(n):
+                t = onset[d]
+                feats[d, t * nb:(t + 1) * nb] = feats_t[t][d]
 
-        labels = self.cache.match(feats)                   # (n,) provisional
+        with self._phase("serve.ingest.match"):
+            labels = self.cache.match(feats)               # (n,) provisional
 
         # paths: hit = label with a VERIFIED cached region; new labels found
         # generations (verification happens at founding — see _discover).
@@ -271,42 +289,37 @@ class FleetServer:
         new_gens = sorted({int(l) for l in labels
                            if l >= 0 and not self.cache.known(l)})
         if new_gens:
-            with _span("serve.discover", server=self._sid,
-                       n_generations=len(new_gens)):
+            with self._phase("serve.ingest.discover",
+                             n_generations=len(new_gens)):
                 self._discover(batch, counts_t, onset, labels, new_gens,
                                genuine)
-        ver = np.asarray([l >= 0 and self.cache.verified(int(l))
-                          for l in labels])
-        path = np.where(~ver, PATH_CONVENTIONAL,
-                        np.where(np.isin(labels, new_gens),
-                                 PATH_DISCOVER, PATH_HIT)).astype(np.int8)
-        conv = path == PATH_CONVENTIONAL
-        self.cache.hits += int((path == PATH_HIT).sum())
-        self.cache.misses += int((path == PATH_DISCOVER).sum())
-        self.cache.conventional += int(conv.sum())
-        for code, name in _PATH_NAMES.items():
-            self._m_path[name].inc(int((path == code).sum()))
-        self._m_gens.set(self.cache.n_generations)
+        with self._phase("serve.ingest.route"):
+            ver = np.asarray([l >= 0 and self.cache.verified(int(l))
+                              for l in labels])
+            path = np.where(~ver, PATH_CONVENTIONAL,
+                            np.where(np.isin(labels, new_gens),
+                                     PATH_DISCOVER, PATH_HIT)).astype(np.int8)
+            conv = path == PATH_CONVENTIONAL
+            self.cache.hits += int((path == PATH_HIT).sum())
+            self.cache.misses += int((path == PATH_DISCOVER).sum())
+            self.cache.conventional += int(conv.sum())
+            for code, name in _PATH_NAMES.items():
+                self._m_path[name].inc(int((path == code).sum()))
+            self._m_gens.set(self.cache.n_generations)
+            internal = self._internal_rows(batch, labels, conv)
 
         # one restricted sweep for every DIMM with a verified region (hit +
         # fresh discoveries); conventional DIMMs take the every-row sweep
-        e2i = np.asarray(batch.ext_to_int, np.int64)
-        internal = np.zeros((n, cfg.k_rows), np.int64)
-        for d in range(n):
-            if not conv[d]:
-                internal[d] = e2i[d][self.cache.ext_rows(labels[d])]
-        tables = self._profile_rows(batch, internal, now)
-        if conv.any():
-            sub = take_batch(batch, np.flatnonzero(conv))
-            tables[conv] = self._profile_all_rows(sub, now)
+        tables = self._sweep(batch, internal, conv, now, "serve.ingest")
 
-        horizon = self._horizon_years(batch)
-        due = now + horizon
-        serials = np.asarray(batch.serial, np.int64)
-        self.state.append(serials, tables, labels, path,
-                          np.full(n, now, np.float32), due, horizon)
-        for s, t in zip(serials, due):
-            heapq.heappush(self._heap, (float(t), int(s)))
+        with self._phase("serve.ingest.commit"):
+            horizon = self._horizon_years(batch)
+            due = now + horizon
+            serials = np.asarray(batch.serial, np.int64)
+            self.state.append(serials, tables, labels, path,
+                              np.full(n, now, np.float32), due, horizon)
+            for s, t in zip(serials, due):
+                heapq.heappush(self._heap, (float(t), int(s)))
 
     # ----------------------------------------------------- discovery (miss)
 
@@ -328,94 +341,129 @@ class FleetServer:
         sub = take_batch(batch, idx)
         padded_sub = pad_batch(sub, pad)
         sub_counts = counts_t[:, idx]                      # (T, m, S, R)
-        T = sub_counts.shape[0]
 
         # per-point recovery on the clone-padded subset: every founding in
         # the fleet's lifetime reuses ONE compiled recovery program
         rec_t = []
-        for t, t_op in enumerate(cfg.campaign_t_ops):
-            lam = row_error_lambda(
-                padded_sub, cfg.campaign_param, float(t_op),
-                temp_C=cfg.campaign_temp_C,
-                refresh_ms=cfg.campaign_refresh_ms,
-                internal_order=True).reshape(self._full, S, R)
-            rec_t.append(recover_mapping_population(
-                _pad0(sub_counts[t], pad).astype(np.int64), lam))
+        with self._phase("serve.ingest.discover.recover"):
+            for t, t_op in enumerate(cfg.campaign_t_ops):
+                lam = row_error_lambda(
+                    padded_sub, cfg.campaign_param, float(t_op),
+                    temp_C=cfg.campaign_temp_C,
+                    refresh_ms=cfg.campaign_refresh_ms,
+                    internal_order=True).reshape(self._full, S, R)
+                rec_t.append(recover_mapping_population(
+                    _pad0(sub_counts[t], pad).astype(np.int64), lam))
         has_signal = sub_counts.max(axis=3) > 0            # (T, m, S)
 
+        with self._phase("serve.ingest.discover.vote"):
+            for gen in new_gens:
+                self._found(gen, idx, labels, onset, sub_counts, rec_t,
+                            has_signal, genuine)
+
+    def _found(self, gen, idx, labels, onset, sub_counts, rec_t, has_signal,
+               genuine) -> None:
+        """Vote one new generation's scramble from its members' recoveries,
+        read its vulnerable rows off the onset-point canonical profile, and
+        cache them (VERIFIED or not)."""
+        cfg = self.cfg
+        R = sub_counts.shape[3]
+        T = sub_counts.shape[0]
         nb = self._nbits
-        for gen in new_gens:
-            pos = np.flatnonzero(labels[idx] == gen)       # positions in sub
-            vb, vx, vc = [], [], []
-            for t in range(T):
-                keep = has_signal[t][pos].reshape(-1)
-                if not keep.any():
-                    continue
-                vb.append(rec_t[t]["ext_bit"][pos].reshape(-1, nb)[keep])
-                vx.append(rec_t[t]["xor"][pos].reshape(-1, nb)[keep])
-                vc.append(rec_t[t]["confidence"][pos].reshape(-1, nb)[keep])
-            if not vb:                                     # nothing observed
-                vb = [rec_t[-1]["ext_bit"][pos[0]]]
-                vx = [rec_t[-1]["xor"][pos[0]]]
-                vc = [rec_t[-1]["confidence"][pos[0]]]
-            vb, vx, vc = (np.concatenate(v) for v in (vb, vx, vc))
-            founder = int(pos[0])
-            t_on = int(onset[idx[founder]])
-            b, x = vote_mapping(vb, vx, vc,
-                                rec_t[t_on]["order_int"][founder, 0])
-            est, i2e = mapping_tables(b, x, R)             # consensus map
-            # generation canonical profile at the onset point, scattered
-            # back through the consensus mapping
-            summed = sub_counts[t_on, pos].sum(axis=(0, 1))  # (R,) external
-            prof = np.zeros(R, np.int64)
-            np.add.at(prof, est, summed)
-            vuln = vulnerable_rows(prof, cfg.k_rows)
-            mass = float(prof[vuln].sum()) / float(max(prof.sum(), 1))
-            # consensus quality: confidence-weighted fraction of the vote
-            # pool that agrees with the voted scramble, per internal bit.
-            # A real generation's members vote coherently (share >~ 0.6);
-            # a cluster of weak-die noise scatters (share <~ 0.5) — and a
-            # tiny pool can be wrong while fully self-consistent, so small
-            # foundings are never trusted regardless of share.
-            agree = (vb == b[None, :]) & (vx == x[None, :])  # (K, nbits)
-            wsum = np.maximum(vc.sum(axis=0), 1e-9)
-            share = (vc * agree).sum(axis=0) / wsum          # per int bit
-            verified = (float(share.mean()) >= cfg.consensus_min_share
-                        and len(pos) >= cfg.min_founders)
-            self.founding_stats[int(gen)] = {
-                "n_founders": int(len(pos)), "region_mass": mass,
-                "conf_mean": float(vc.mean()),
-                "share_mean": float(share.mean()),
-                "share_min": float(share.min()),
-                "all_genuine": bool(genuine[idx[pos]].all()),
-                "verified": verified}
-            self.cache.install(gen, i2e[vuln], verified=verified)
+        pos = np.flatnonzero(labels[idx] == gen)       # positions in sub
+        vb, vx, vc = [], [], []
+        for t in range(T):
+            keep = has_signal[t][pos].reshape(-1)
+            if not keep.any():
+                continue
+            vb.append(rec_t[t]["ext_bit"][pos].reshape(-1, nb)[keep])
+            vx.append(rec_t[t]["xor"][pos].reshape(-1, nb)[keep])
+            vc.append(rec_t[t]["confidence"][pos].reshape(-1, nb)[keep])
+        if not vb:                                     # nothing observed
+            vb = [rec_t[-1]["ext_bit"][pos[0]]]
+            vx = [rec_t[-1]["xor"][pos[0]]]
+            vc = [rec_t[-1]["confidence"][pos[0]]]
+        vb, vx, vc = (np.concatenate(v) for v in (vb, vx, vc))
+        founder = int(pos[0])
+        t_on = int(onset[idx[founder]])
+        b, x = vote_mapping(vb, vx, vc,
+                            rec_t[t_on]["order_int"][founder, 0])
+        est, i2e = mapping_tables(b, x, R)             # consensus map
+        # generation canonical profile at the onset point, scattered
+        # back through the consensus mapping
+        summed = sub_counts[t_on, pos].sum(axis=(0, 1))  # (R,) external
+        prof = np.zeros(R, np.int64)
+        np.add.at(prof, est, summed)
+        vuln = vulnerable_rows(prof, cfg.k_rows)
+        mass = float(prof[vuln].sum()) / float(max(prof.sum(), 1))
+        # consensus quality: confidence-weighted fraction of the vote
+        # pool that agrees with the voted scramble, per internal bit.
+        # A real generation's members vote coherently (share >~ 0.6);
+        # a cluster of weak-die noise scatters (share <~ 0.5) — and a
+        # tiny pool can be wrong while fully self-consistent, so small
+        # foundings are never trusted regardless of share.
+        agree = (vb == b[None, :]) & (vx == x[None, :])  # (K, nbits)
+        wsum = np.maximum(vc.sum(axis=0), 1e-9)
+        share = (vc * agree).sum(axis=0) / wsum          # per int bit
+        verified = (float(share.mean()) >= cfg.consensus_min_share
+                    and len(pos) >= cfg.min_founders)
+        self.founding_stats[int(gen)] = {
+            "n_founders": int(len(pos)), "region_mass": mass,
+            "conf_mean": float(vc.mean()),
+            "share_mean": float(share.mean()),
+            "share_min": float(share.min()),
+            "all_genuine": bool(genuine[idx[pos]].all()),
+            "verified": verified}
+        self.cache.install(gen, i2e[vuln], verified=verified)
 
     # --------------------------------------------------------- profiling
 
-    def _profile_rows(self, batch, internal_rows, now: float) -> np.ndarray:
+    def _internal_rows(self, batch, labels, conv) -> np.ndarray:
+        """(C, k_rows) internal test rows: each DIMM's generation region
+        (cached external rows) through its own scramble; conventional DIMMs
+        keep zeros (their sweep covers every row)."""
+        e2i = np.asarray(batch.ext_to_int, np.int64)
+        internal = np.zeros((len(labels), self.cfg.k_rows), np.int64)
+        for d in range(len(labels)):
+            if not conv[d]:
+                internal[d] = e2i[d][self.cache.ext_rows(labels[d])]
+        return internal
+
+    def _sweep(self, batch, internal_rows, conv, now: float,
+               prefix: str) -> np.ndarray:
+        """(C, 4) tables: one restricted sweep of the chunk at its internal
+        rows, then the every-row sweep of its conventional DIMMs."""
+        tables = self._profile_rows(batch, internal_rows, now, prefix)
+        if conv.any():
+            sub = take_batch(batch, np.flatnonzero(conv))
+            tables[conv] = self._profile_all_rows(sub, now, prefix)
+        return tables
+
+    def _profile_rows(self, batch, internal_rows, now: float,
+                      prefix: str = "serve.ingest") -> np.ndarray:
         """(C, 4) tables at per-DIMM internal regions through the one
-        compiled serve program (clone-padded chunk, donated buffers)."""
+        compiled serve program (clone-padded chunk, donated buffers), under
+        the span ``<prefix>.hit_sweep``."""
         n = batch.n_dimms
-        pad = self._full - n
-        padded = pad_batch(batch, pad)
-        rows = _pad0(np.asarray(internal_rows, np.int32), pad)
-        adder = self._adder(padded, now)
-        with _span("serve.profile_rows", server=self._sid, n=n) as sp:
+        with self._phase(prefix + ".hit_sweep", n=n):
+            pad = self._full - n
+            padded = pad_batch(batch, pad)
+            rows = _pad0(np.asarray(internal_rows, np.int32), pad)
+            adder = self._adder(padded, now)
             out = _chunk_jitted("serve_profile", _profile_impl, self._statics,
                                 donate=(0, 3))(padded, jnp.asarray(rows),
                                                self._stress,
                                                jnp.asarray(adder))
-            sp.bind(out)
-        return np.array(out, np.float32)[:n, 0]
+            return np.array(out, np.float32)[:n, 0]
 
-    def _profile_all_rows(self, batch, now: float) -> np.ndarray:
-        """Conventional every-row sweep for the signatureless fallback."""
+    def _profile_all_rows(self, batch, now: float,
+                          prefix: str = "serve.ingest") -> np.ndarray:
+        """Conventional every-row sweep for the signatureless fallback,
+        under the span ``<prefix>.every_row``."""
         cfg = self.cfg
-        aged = dataclasses.replace(
-            batch, age_years=np.full(batch.n_dimms, now, np.float32))
-        with _span("serve.conventional_sweep", server=self._sid,
-                   n=batch.n_dimms):
+        with self._phase(prefix + ".every_row", n=batch.n_dimms):
+            aged = dataclasses.replace(
+                batch, age_years=np.full(batch.n_dimms, now, np.float32))
             return np.asarray(profile_population_arrays(
                 aged, region="all", temp_C=cfg.profile_temp_C,
                 refresh_ms=cfg.profile_refresh_ms,
@@ -507,44 +555,40 @@ class FleetServer:
         """Advance the fleet clock and re-profile every DIMM whose deadline
         passed, in chunked sweeps at the cached regions under the aged
         condition.  Returns the re-profile count."""
-        due: list[int] = []
-        while self._heap and self._heap[0][0] <= now:
-            _, s = heapq.heappop(self._heap)
-            # stale heap entries (superseded by a later re-profile) drop out
-            i = self.state.index.get(s)
-            if i is not None and self.state.view("due_at")[i] <= now:
-                due.append(s)
-        with _span("serve.tick", server=self._sid, now=now,
-                   reprofiled=len(due)):
+        with self._phase("serve.tick", server=self._sid, now=now) as sp:
+            due: list[int] = []
+            with self._phase("serve.tick.drain"):
+                while self._heap and self._heap[0][0] <= now:
+                    _, s = heapq.heappop(self._heap)
+                    # stale heap entries (superseded by a later re-profile)
+                    # drop out
+                    i = self.state.index.get(s)
+                    if i is not None and self.state.view("due_at")[i] <= now:
+                        due.append(s)
+            sp.set(reprofiled=len(due))
             for lo in range(0, len(due), self._full):
                 self._reprofile(np.asarray(due[lo:lo + self._full]), now)
-        self._m_reprof.inc(len(due))
+            self._m_reprof.inc(len(due))
         self.clock = max(self.clock, now)
         return {"now": now, "reprofiled": len(due)}
 
     def _reprofile(self, serials: np.ndarray, now: float) -> None:
-        cfg = self.cfg
-        serials = np.sort(serials)
-        runs = np.split(serials, np.flatnonzero(np.diff(serials) != 1) + 1)
-        batch = concat_batches([self.stream.chunk(int(r[0]), int(r[-1]) + 1)
-                                for r in runs])
-        rows_idx = self.state.rows_for(serials)
-        labels = self.state.view("label")[rows_idx]
-        path = self.state.view("path")[rows_idx]
-        conv = path == PATH_CONVENTIONAL
-        e2i = np.asarray(batch.ext_to_int, np.int64)
-        internal = np.zeros((len(serials), cfg.k_rows), np.int64)
-        for j in range(len(serials)):
-            if not conv[j]:
-                internal[j] = e2i[j][self.cache.ext_rows(labels[j])]
-        tables = self._profile_rows(batch, internal, now)
-        if conv.any():
-            sub = take_batch(batch, np.flatnonzero(conv))
-            tables[conv] = self._profile_all_rows(sub, now)
-        due = now + self.state.view("horizon")[rows_idx]
-        self.state.update_rows(rows_idx, tables, now, due)
-        for s, t in zip(serials, due):
-            heapq.heappush(self._heap, (float(t), int(s)))
+        with self._phase("serve.tick.gather", n=len(serials)):
+            serials = np.sort(serials)
+            runs = np.split(serials,
+                            np.flatnonzero(np.diff(serials) != 1) + 1)
+            batch = concat_batches([
+                self.stream.chunk(int(r[0]), int(r[-1]) + 1) for r in runs])
+            rows_idx = self.state.rows_for(serials)
+            labels = self.state.view("label")[rows_idx]
+            conv = self.state.view("path")[rows_idx] == PATH_CONVENTIONAL
+            internal = self._internal_rows(batch, labels, conv)
+        tables = self._sweep(batch, internal, conv, now, "serve.tick")
+        with self._phase("serve.tick.commit"):
+            due = now + self.state.view("horizon")[rows_idx]
+            self.state.update_rows(rows_idx, tables, now, due)
+            for s, t in zip(serials, due):
+                heapq.heappush(self._heap, (float(t), int(s)))
 
     # --------------------------------------------------------- checkpoint
 

@@ -3,15 +3,20 @@
 Instrumentation lives strictly at host boundaries, so it must be invisible
 to the computation: enabled-vs-disabled runs are BITWISE identical on the
 profiling substrate, the streamed scans, and the fleet server
-(test_*_bit_parity), and running fully instrumented adds ZERO compiled
-programs beyond the warmed cache (test_no_new_compiles_under_tracing).
-The rest pins the data plane itself: histogram percentile math, label
-handling, the Prometheus text exposition, the Chrome trace-event schema,
-the memsim compat shim, and the serve-layer ``metrics()`` consistency.
+(test_*_bit_parity), and running under a profiler trace adds ZERO
+compiled programs beyond the warmed cache
+(test_no_new_compiles_under_tracing).  The rest pins the data plane
+itself: histogram percentile math, label handling, the Prometheus text
+exposition, spans in the profiler trace (exact names, nesting, the serve
+phases), the memsim compat shim, and the serve-layer ``metrics()``
+consistency.
 """
 import json
 import math
+import tempfile
+from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
@@ -143,29 +148,58 @@ def test_snapshot_round_trips_through_json(registry):
 
 # ---------------------------------------------------------------- tracing
 
-def test_span_records_chrome_events_only_while_tracing(tmp_path):
-    obs.start_tracing()
-    try:
-        with obs.span("test.outer", key="v") as sp:
-            with obs.span("test.inner"):
-                pass
-        assert sp.duration_s > 0
-    finally:
-        events = obs.stop_tracing()
-    with obs.span("test.after_stop"):   # must NOT be collected
-        pass
-    assert [e["name"] for e in events] == ["test.inner", "test.outer"]
-    for e in events:
-        assert e["ph"] == "X" and e["cat"] == "repro"
-        assert e["dur"] >= 0 and "ts" in e and "pid" in e and "tid" in e
-    assert events[1]["args"] == {"key": "v"}
-    assert obs.trace_events() == events   # buffer kept after stop
+def _host_spans(log_dir, prefix: str) -> list[tuple]:
+    """(name, start_ns, end_ns, stats) of every host event of the newest
+    profiler trace under ``log_dir`` whose name starts with ``prefix``."""
+    path = sorted(Path(log_dir).rglob("*.xplane.pb"))[-1]
+    prof = jax.profiler.ProfileData.from_file(str(path))
+    return sorted((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                   dict(e.stats))
+                  for plane in prof.planes if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith(prefix))
 
+
+def _inside(child: tuple, parent: tuple) -> bool:
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_span_records_chrome_events_only_while_tracing(tmp_path):
+    """Nested spans are events of the profiler trace under exactly their
+    names, the inner inside the outer, args as metadata; a span outside
+    the session is not recorded."""
+    with obs.span("test.before"):
+        pass
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("test.outer", key="v", n=3) as sp:
+            with obs.span("test.outer.inner") as inner:
+                inner.set(late=7)
+    with obs.span("test.after_stop"):
+        pass
+    assert sp.duration_s > 0
+    spans = _host_spans(tmp_path, "test.")
+    assert [s[0] for s in spans] == ["test.outer", "test.outer.inner"]
+    outer, inner = spans
+    assert _inside(inner, outer) and inner[2] > inner[1]
+    assert outer[3] == {"key": "v", "n": 3}
+    assert inner[3] == {"late": 7}
+
+
+def test_trace_out_writes_chrome_json_with_spans_and_xla(tmp_path):
+    """``launch/serve.py --trace-out F`` (``obs.record``) writes Chrome
+    trace-event JSON holding the serving phase spans, with their args, and
+    the XLA runtime's own events."""
+    from repro.launch.serve import main
     path = tmp_path / "trace.json"
-    obs.write_chrome_trace(path)
+    main(["--fleet", "8", "--chunk", "4", "--trace-out", str(path)])
     doc = json.loads(path.read_text())
-    assert doc["displayTimeUnit"] == "ms"
-    assert doc["traceEvents"] == events
+    events = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    chunks = [e for e in events if e["name"] == "serve.ingest"]
+    assert [e["args"]["lo"] for e in chunks] == ["0", "4"]
+    assert all(e["dur"] > 0 for e in chunks)
+    assert {"serve.fleet_ingest", "serve.ingest.campaign",
+            "serve.ingest.hit_sweep"} <= {e["name"] for e in events}
+    assert any(not e["name"].startswith("serve.") for e in events)
 
 
 def test_span_observes_into_histogram(registry):
@@ -173,6 +207,57 @@ def test_span_observes_into_histogram(registry):
     with obs.span("test.timed", hist=h):
         pass
     assert h.count == 1 and h.sum >= 0
+
+
+INGEST_PHASES = {"serve.ingest", "serve.ingest.campaign",
+                 "serve.ingest.signature", "serve.ingest.match",
+                 "serve.ingest.discover", "serve.ingest.discover.recover",
+                 "serve.ingest.discover.vote", "serve.ingest.route",
+                 "serve.ingest.hit_sweep", "serve.ingest.commit"}
+TICK_PHASES = {"serve.tick", "serve.tick.drain", "serve.tick.gather",
+               "serve.tick.hit_sweep", "serve.tick.commit"}
+
+
+def test_fleet_server_phase_spans_nest_in_the_profiler_trace(tmp_path):
+    """An ingest that founds generations and a tick that re-profiles the
+    whole fleet emit every phase span, each child inside a span of its
+    parent's name; each phase observes into repro_serve_phase_seconds."""
+    server = FleetServer(FLEET, FleetConfig(chunk_size=CHUNK))
+    server.ingest(CHUNK, now=0.0)                       # warm, founds
+    with jax.profiler.trace(str(tmp_path)):
+        first = server.ingest(CHUNK, now=0.0)
+        server.ingest(now=0.0)
+        ticked = server.tick(100.0)
+    assert ticked["reprofiled"] == D
+    spans = _host_spans(tmp_path, "serve.")
+    names = {s[0] for s in spans}
+    assert INGEST_PHASES | TICK_PHASES <= names
+    assert names <= INGEST_PHASES | TICK_PHASES | {
+        "serve.ingest.every_row", "serve.tick.every_row"}
+    for child in spans:
+        parent = child[0].rpartition(".")[0]
+        if parent == "serve":
+            continue
+        assert any(p[0] == parent and _inside(child, p) for p in spans), \
+            child[0]
+    chunks = [s for s in spans if s[0] == "serve.ingest"]
+    assert len(chunks) == 2                             # [5, 10), [10, 12)
+    assert chunks[0][3]["lo"] == CHUNK and chunks[0][3]["hi"] == 2 * CHUNK
+    founding = [c for c in chunks if any(
+        s[0] == "serve.ingest.discover" and _inside(s, c) for s in spans)]
+    assert founding and first["n_generations"] > 0
+    ticks = [s for s in spans if s[0] == "serve.tick"]
+    assert len(ticks) == 1 and ticks[0][3]["reprofiled"] == D
+    drain = next(s for s in spans if s[0] == "serve.tick.drain")
+    assert ticks[0][1] <= drain[1] and drain[2] <= min(
+        s[1] for s in spans if s[0] == "serve.tick.gather")
+    phase_hist = obs.REGISTRY.get("repro_serve_phase_seconds")
+    for name in TICK_PHASES:           # the warm ingest ticked nothing
+        hist = phase_hist.labels(server=server._sid, phase=name)
+        assert hist.count == sum(s[0] == name for s in spans), name
+    for name in INGEST_PHASES:         # the warm ingest counts too
+        hist = phase_hist.labels(server=server._sid, phase=name)
+        assert hist.count >= sum(s[0] == name for s in spans), name
 
 
 # ------------------------------------------------- the bit-parity contract
@@ -183,11 +268,8 @@ def _profile_disabled_then_enabled(fn):
         off = fn()
     finally:
         obs.enable()
-    obs.start_tracing()
-    try:
+    with tempfile.TemporaryDirectory() as tmp, jax.profiler.trace(tmp):
         on = fn()
-    finally:
-        obs.stop_tracing()
     return off, on
 
 
@@ -226,11 +308,8 @@ def test_no_new_compiles_under_tracing():
         "repro_compile_programs_total", cache="chunk",
         entry="stream_profile")
     c0 = compiles()
-    obs.start_tracing()
-    try:
+    with tempfile.TemporaryDirectory() as tmp, jax.profiler.trace(tmp):
         st.stream_profile_population(FLEET, chunk_size=CHUNK)
-    finally:
-        obs.stop_tracing()
     assert len(substrate._CHUNK_JIT_CACHE) == n_cache
     assert compiles() == c0
     # and the reuse counter DID move: the cache was hit, not bypassed
