@@ -1254,14 +1254,14 @@ def _row_lambda_impl(batch: DimmBatch, t_op, stress, adder, *,
     S, P = g.subarrays, stress.shape[0]
     _, d_mat, _ = _geom_consts(g)
     d_mat = jnp.asarray(d_mat)
-    fp_d = functools.partial(ops.fail_prob_batch, cols=g.cols_per_mat,
-                             pallas=pallas)               # over DIMMs
+    row_sums = functools.partial(ops.fail_prob_batch, cols=g.cols_per_mat,
+                                 reduce_rows=True, pallas=pallas)  # over DIMMs
 
     def per_subarray(_, s):
         def per_pattern(acc_p, pi):
             coeffs = _pack_coeffs(batch, pidx, t_op, stress[pi], adder, 0, s)
-            grids = fp_d(jnp.take(batch.row_src, s, axis=1), d_mat, coeffs)
-            return acc_p + 2 * grids.sum(axis=(1, 3)) * g.chips, None
+            sums = row_sums(jnp.take(batch.row_src, s, axis=1), d_mat, coeffs)
+            return acc_p + 2 * sums * g.chips, None
         D, R = batch.serial.shape[0], g.rows_per_mat
         exp_row, _ = jax.lax.scan(per_pattern, jnp.zeros((D, R), jnp.float32),
                                   jnp.arange(P))

@@ -8,6 +8,11 @@ offsets (folded into the coefficient row), the heavy-tail weak-cell mixture
 into the ``row_src`` index table).  HBM traffic is one read of the row-source
 and coefficient rows and one write of the (mats, rows, cols) grid.
 
+Callers that only sum the grid over mats and columns (the campaign's
+expected row counts) ask for ``reduce_rows=True`` instead: the same
+``cell_probs`` evaluated on register-sized blocks and accumulated in place,
+so the kernel writes (rows,) floats and no grid reaches HBM.
+
 The call is vmap-able over DIMMs / chips / subarrays / patterns — the
 batching rule adds grid dimensions — which is how core/substrate.py profiles
 the whole population.  Semantics match ``kernels/ref.py::fail_prob`` to one
@@ -29,6 +34,9 @@ N_COEFFS = 9  # base_eff, k_bl', k_wl', k_mat', k_row', t_op, sigma, rate, ns
 # operating-point row: N_COEFFS access coefficients plus the voltage shift
 # and the retention channel (ret_base, ret_k, ret_x, ret_sigma, ret_drop)
 N_OP_COEFFS = 15
+# the row-sum mode's row block (eight f32 sublane tiles) and column strip
+_ROW_BLOCK = 64
+_LANES = 128
 
 
 def cell_probs(rf, colf, even, d_mat, cf, n_rows: int, n_cols: int,
@@ -130,17 +138,88 @@ def _row_grid(row_src, row_tile: int | None):
     return row_src, R, row_tile
 
 
+def _row_sum_call(row_src, d_mat, coeffs, cols: int, open_bitline: bool,
+                  row_tile: int | None, interpret: bool):
+    """The (R,) sum over mats and columns of ``cell_probs``, with no grid.
+
+    One program owns one row tile and walks it in blocks of ``_ROW_BLOCK``
+    rows; within a block, column strips of up to ``_LANES`` lanes; within a
+    strip, the mats, read as SMEM scalars.  The row source and the column
+    iota become full (block, strip) arrays once a row block and once a
+    strip, so every operation of ``cell_probs`` works on whole vregs.  The
+    mat loop is unrolled: its cells are independent chains the scheduler
+    interleaves, and what does not depend on the mat is the same value in
+    each.  Probabilities accumulate in a (block, strip) array that never
+    leaves the core; its lanes are summed once at the block's end.  HBM
+    traffic is the row-source read and R floats written."""
+    row_src = jnp.asarray(row_src, jnp.int32).reshape(-1, 1)
+    d_mat = jnp.asarray(d_mat, jnp.float32).reshape(1, -1)
+    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(1, N_COEFFS)
+    R = row_src.shape[0]
+    tile = -(-(row_tile or R) // _ROW_BLOCK) * _ROW_BLOCK
+    row_src, R, tile = _row_grid(row_src, tile)
+    Rp, M = row_src.shape[0], d_mat.shape[1]
+    strip = _LANES if cols % _LANES == 0 else cols
+    f32 = jnp.float32
+
+    def kernel(rs_ref, dm_ref, cf_ref, out_ref):
+        cf = [cf_ref[0, k] for k in range(N_COEFFS)]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (_ROW_BLOCK, strip), 1)
+
+        def row_block(b, carry):
+            r0 = pl.multiple_of(b * _ROW_BLOCK, _ROW_BLOCK)
+            rf = jnp.broadcast_to(
+                rs_ref[pl.ds(r0, _ROW_BLOCK), :].astype(f32),
+                (_ROW_BLOCK, strip))
+
+            def col_strip(s, acc):
+                col = lane + s * strip
+                colf, even = col.astype(f32), (col % 2) == 0
+
+                def mat(m, acc):
+                    return acc + cell_probs(rf, colf, even, dm_ref[0, m], cf,
+                                            R, cols, open_bitline)
+
+                return jax.lax.fori_loop(0, M, mat, acc, unroll=True)
+
+            acc = jax.lax.fori_loop(0, cols // strip, col_strip,
+                                    jnp.zeros((_ROW_BLOCK, strip), f32))
+            out_ref[pl.ds(r0, _ROW_BLOCK), :] = acc.sum(axis=1, keepdims=True)
+            return carry
+
+        jax.lax.fori_loop(0, tile // _ROW_BLOCK, row_block, 0)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    out = pl.pallas_call(
+        kernel,
+        grid=(Rp // tile,),
+        in_specs=[pl.BlockSpec((tile, 1), lambda j: (j, 0)), smem, smem],
+        out_specs=pl.BlockSpec((tile, 1), lambda j: (j, 0)),
+        out_shape=jax.ShapeDtypeStruct((Rp, 1), f32),
+        interpret=interpret,
+    )(row_src, d_mat, coeffs)
+    return out[:R, 0]
+
+
 @functools.partial(jax.jit, static_argnames=("cols", "open_bitline",
-                                             "row_tile", "interpret"))
+                                             "row_tile", "reduce_rows",
+                                             "interpret"))
 def fail_prob(row_src, d_mat, coeffs, *, cols: int, interpret: bool,
-              open_bitline: bool = True, row_tile: int | None = None):
+              open_bitline: bool = True, row_tile: int | None = None,
+              reduce_rows: bool = False):
     """row_src: (R,) int32 repair-resolved internal rows; d_mat: (M,) f32
     precharge-arrival delays; coeffs: (N_COEFFS,) f32 folded coefficient row.
-    Returns the (M, R, C) failure-probability grid.
+    Returns the (M, R, C) failure-probability grid, or with ``reduce_rows``
+    its (R,) sum over mats and columns, computed without writing the grid.
 
     ``row_tile`` splits the row axis into a second grid dimension (masked
     tail via pad-to-tile + slice-back); per-cell results are bit-identical at
-    any tile because each row's computation is independent."""
+    any tile because each row's computation is independent.  In the row-sum
+    mode it is the rows one program owns, rounded up to whole row blocks."""
+    if reduce_rows:
+        return _row_sum_call(row_src, d_mat, coeffs, cols, open_bitline,
+                             row_tile, interpret)
+
     def probs(rf, colf, even, dm, cf, n_rows):
         return cell_probs(rf, colf, even, dm, cf, n_rows, cols, open_bitline)
 

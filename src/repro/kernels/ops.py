@@ -56,6 +56,13 @@ _KERNEL_TRACES = _OBS_REGISTRY.counter(
     "kernel dispatch traces by (kernel, backend tag); counts lowerings, "
     "not executions",
     labelnames=("kernel", "backend"))
+# lowerings that asked a kernel for its row sums (``reduce_rows``) instead
+# of its cell grid: shows that a caller's reduction moved into the kernel
+_ROW_REDUCE = _OBS_REGISTRY.counter(
+    "repro_kernel_row_reduce_total",
+    "kernel dispatch traces in the row-sum output mode by (kernel, backend "
+    "tag); counts lowerings, not executions",
+    labelnames=("kernel", "backend"))
 
 _COMPILED_TAGS = (GPU, TPU)
 _FORCED: list[str] = []  # force_backend stack (innermost last)
@@ -158,6 +165,8 @@ def _dispatch(name: str, args: tuple, kw: dict, pallas: bool | None,
     spec = REGISTRY[name]
     route, tag = _resolve(spec, pallas)
     _KERNEL_TRACES.labels(kernel=name, backend=tag).inc()
+    if kw.get("reduce_rows"):
+        _ROW_REDUCE.labels(kernel=name, backend=tag).inc()
     if route == "ref":
         return spec.oracle(*args, **kw)
     if tiles is None:
@@ -181,24 +190,28 @@ def secded_syndrome(code_bits, tile: int | None = None, *,
 
 
 def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True,
-              row_tile: int | None = None, pallas: bool | None = None):
-    """``pallas=None`` resolves the backend tag at trace time; callers that
+              reduce_rows: bool = False, row_tile: int | None = None,
+              pallas: bool | None = None):
+    """The (M, R, C) grid, or with ``reduce_rows`` its (R,) row sums.
+    ``pallas=None`` resolves the backend tag at trace time; callers that
     cache compiled programs pass the resolved bool so the backend choice keys
     their cache (the ``substrate._shuffling_jit`` convention)."""
     return _dispatch("fail_prob", (row_src, d_mat, coeffs),
-                     dict(cols=cols, open_bitline=open_bitline), pallas,
+                     dict(cols=cols, open_bitline=open_bitline,
+                          reduce_rows=reduce_rows), pallas,
                      None if row_tile is None else {"row_tile": row_tile})
 
 
 def fail_prob_batch(row_src, d_mat, coeffs, *, cols: int,
-                    open_bitline: bool = True, pallas: bool | None = None):
+                    open_bitline: bool = True, reduce_rows: bool = False,
+                    pallas: bool | None = None):
     """``fail_prob`` vmapped over a leading population (DIMM) axis of
     ``row_src``/``coeffs`` — the dispatch the batched substrate and its
     sharded routes share (one dispatch site: the per-DIMM ``fail_prob``)."""
     if pallas is None:
         pallas = use_pallas()
     fn = functools.partial(fail_prob, cols=cols, open_bitline=open_bitline,
-                           pallas=pallas)
+                           reduce_rows=reduce_rows, pallas=pallas)
     return jax.vmap(fn, in_axes=REGISTRY["fail_prob"].batch_in_axes)(
         row_src, d_mat, coeffs)
 

@@ -11,9 +11,11 @@ from repro.kernels import shuffle as _shuffle_mod
 from repro.models.rwkv6 import wkv6_scan as _wkv6_scan
 
 
-def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True):
+def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True,
+              reduce_rows: bool = False):
     """(M, R, C) failure-probability grid — pure-jnp oracle of the Pallas
-    kernel in kernels/fail_prob.py (same formula helper, same bits)."""
+    kernel in kernels/fail_prob.py (same formula helper, same bits).  With
+    ``reduce_rows``, the grid's (R,) sum over mats and columns."""
     from repro.kernels.fail_prob import cell_probs
     row_src = jnp.asarray(row_src, jnp.int32)
     d_mat = jnp.asarray(d_mat, jnp.float32)
@@ -24,8 +26,9 @@ def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True):
     colf = jax.lax.broadcasted_iota(jnp.float32, (d_mat.shape[0], R, cols), 2)
     even = (jax.lax.broadcasted_iota(jnp.int32, (d_mat.shape[0], R, cols), 2)
             % 2) == 0
-    return cell_probs(rf, colf, even, d_mat[:, None, None], coeffs, R, cols,
+    grid = cell_probs(rf, colf, even, d_mat[:, None, None], coeffs, R, cols,
                       open_bitline)
+    return grid.sum(axis=(0, 2)) if reduce_rows else grid
 
 
 def fail_prob_op(row_src, d_mat, coeffs, *, cols: int,

@@ -1,9 +1,12 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp oracles (interpret mode)."""
+import jax
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.fail_prob import fail_prob as fp_pallas
 from repro.kernels.rc_transient import rc_transient as rc_pallas
+from repro.kernels.registry import REGISTRY
 from repro.kernels.secded import encode_checks, syndrome
 from repro.kernels.shuffle import apply_shuffle
 from repro.kernels.wkv6 import wkv6 as wkv6_pallas
@@ -184,6 +187,56 @@ def test_fail_prob_default_tile_bitwise_matches_untiled():
                                  pallas=True)),
         np.asarray(fp_pallas(row_src, d_mat, coeffs, cols=64,
                              interpret=True)))
+
+
+# (rows, mats, cols) of the row-sum cases: TINY's and FULL's widths, and
+# 200 rows, which no tile of the registry divides
+ROW_SUM_SHAPES = {"tiny": (64, 4, 64), "rows200": (200, 4, 128),
+                  "full": (512, 16, 512)}
+ROW_SUM_TILES = REGISTRY["fail_prob"].tile_space + ({"row_tile": 100},)
+
+
+@pytest.mark.parametrize("shape", ROW_SUM_SHAPES)
+@pytest.mark.parametrize(
+    "tiles", ROW_SUM_TILES,
+    ids=[str(t.get("row_tile", "default")) for t in ROW_SUM_TILES])
+def test_fail_prob_row_sums_match_oracle(shape, tiles):
+    """The row-sum mode (interpret) against the oracle's row sums, compiled
+    as every caller compiles it, at every tile of the registry and one that
+    divides no row count.  The kernel sums in another order than XLA, and
+    the compiled formula rounds a few cells differently from the oracle's
+    op-by-op evaluation, so the sums agree to 1e-5, not bit for bit."""
+    R, M, C = ROW_SUM_SHAPES[shape]
+    rng = np.random.default_rng(7)
+    row_src = rng.integers(0, R, R).astype(np.int32)
+    d_mat = np.linspace(0.1, 1.0, M).astype(np.float32)
+    coeffs = np.array([3.9, 2.1, 0.4, 0.8, 0.4, 7.5, 0.15, 3e-6, 3.5],
+                      np.float32)
+    oracle = jax.jit(ref.fail_prob, static_argnames=("cols", "open_bitline",
+                                                     "reduce_rows"))
+    for open_bitline in (True, False):
+        want = np.asarray(oracle(row_src, d_mat, coeffs, cols=C,
+                                 open_bitline=open_bitline, reduce_rows=True))
+        got = np.asarray(fp_pallas(row_src, d_mat, coeffs, cols=C,
+                                   open_bitline=open_bitline, interpret=True,
+                                   reduce_rows=True, **tiles))
+        assert got.shape == (R,)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=0)
+
+
+def test_fail_prob_oracle_row_sums_are_the_grid_summed():
+    """The oracle's row-sum mode is its grid summed over mats and columns,
+    bit for bit: the cpu-ref route traces the reduction it always did."""
+    rng = np.random.default_rng(8)
+    row_src = rng.integers(0, 128, 128).astype(np.int32)
+    d_mat = np.linspace(0.1, 1.0, 4).astype(np.float32)
+    coeffs = np.array([3.9, 2.1, 0.4, 0.8, 0.4, 7.5, 0.15, 3e-6, 3.5],
+                      np.float32)
+    grid = ref.fail_prob(row_src, d_mat, coeffs, cols=64)
+    np.testing.assert_array_equal(
+        np.asarray(ref.fail_prob(row_src, d_mat, coeffs, cols=64,
+                                 reduce_rows=True)),
+        np.asarray(grid.sum(axis=(0, 2))))
 
 
 @pytest.mark.parametrize("tile", [32, 100])

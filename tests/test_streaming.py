@@ -3,6 +3,7 @@ substrate at multiple chunk sizes (including one that does not divide D),
 online-reduction exactness contracts, the one-compiled-chunk-program rule,
 packed error grids, the incremental generation clusterer, and the peak-RSS
 regression that proves no dense population tensor is ever materialized."""
+import functools
 import os
 import subprocess
 import sys
@@ -366,6 +367,81 @@ def test_hash_poisson_counts_chunk_invariant():
         [st.hash_poisson_counts(FLEET.chunk(lo, hi), "trp", 7.5)
          for lo, hi in chunk_spans(D, 5)])
     np.testing.assert_array_equal(whole, parts)
+
+
+# ------------------------------------------------- campaign row sums
+
+def _grid_then_sum(monkeypatch):
+    """Route the campaign's row sums through the ``fail_prob`` grid and
+    XLA's sum over mats and columns (the formula the row-sum mode
+    replaced), in programs traced afresh."""
+    from repro.kernels import ops
+    batch_fn = ops.fail_prob_batch
+
+    def grids_summed(*args, reduce_rows=False, **kw):
+        out = batch_fn(*args, **kw)
+        return out.sum(axis=(1, 3)) if reduce_rows else out
+
+    monkeypatch.setattr(ops, "fail_prob_batch", grids_summed)
+    monkeypatch.setattr(substrate, "_CHUNK_JIT_CACHE", {})
+    # a new partial object: jit's trace cache keys on the function, so
+    # jitting ``_row_lambda_impl`` itself would replay an earlier trace
+    monkeypatch.setattr(substrate, "_row_lambda_jit", jax.jit(
+        functools.partial(substrate._row_lambda_impl),
+        static_argnames=("pidx", "iters", "internal", "pallas")))
+
+
+def test_campaign_row_sums_bit_identical_to_grid_then_sum(monkeypatch):
+    """On the cpu-ref route the kernel's row-sum mode is the oracle's grid
+    summed, so λ and the campaign's Poisson counts keep every bit."""
+    kw = dict(refresh_ms=256.0)
+    lam = substrate.row_error_lambda(BATCH, "trp", 7.5, **kw)
+    lam_int = substrate.row_error_lambda(BATCH, "trcd", 10.0,
+                                         internal_order=True)
+    counts = st.hash_poisson_counts(BATCH, "trp", 7.5, seed=3, **kw)
+    _grid_then_sum(monkeypatch)
+    np.testing.assert_array_equal(
+        lam, substrate.row_error_lambda(BATCH, "trp", 7.5, **kw))
+    np.testing.assert_array_equal(
+        lam_int, substrate.row_error_lambda(BATCH, "trcd", 10.0,
+                                            internal_order=True))
+    np.testing.assert_array_equal(
+        counts, st.hash_poisson_counts(BATCH, "trp", 7.5, seed=3, **kw))
+
+
+def _row_reduce_lowerings():
+    from repro import obs
+    from repro.kernels import ops
+    return obs.REGISTRY.value("repro_kernel_row_reduce_total",
+                              kernel="fail_prob", backend=ops.backend_tag())
+
+
+def test_campaign_lowering_counts_a_row_sum(monkeypatch):
+    """Lowering the campaign asks ``fail_prob`` for row sums, and the
+    counter beside the kernel-trace counter shows it."""
+    monkeypatch.setattr(substrate, "_CHUNK_JIT_CACHE", {})
+    before = _row_reduce_lowerings()
+    st.hash_poisson_counts(BATCH, "trp", 7.5, refresh_ms=256.0)
+    assert _row_reduce_lowerings() > before
+
+
+def test_fail_prob_grids_lower_the_grid_mode(monkeypatch):
+    """Callers that need the cells keep the grid: ``fail_prob_grids``
+    lowers the grid mode and leaves the row-sum counter alone."""
+    from repro import obs
+    from repro.kernels import ops
+    monkeypatch.setattr(substrate, "_fail_prob_jit", jax.jit(
+        functools.partial(substrate._fail_prob_impl),
+        static_argnames=("cols", "pallas")))
+    traces = lambda: obs.REGISTRY.value(  # noqa: E731
+        "repro_kernel_traces_total", kernel="fail_prob",
+        backend=ops.backend_tag())
+    before, rows_before = traces(), _row_reduce_lowerings()
+    grids = fail_prob_grids(BATCH, "trp", 7.5)
+    g = BATCH.geom
+    assert grids.shape == (D, g.mats_x, g.rows_per_mat, g.cols_per_mat)
+    assert traces() > before
+    assert _row_reduce_lowerings() == rows_before
 
 
 def test_canonical_internal_profiles_mean_combine():

@@ -13,6 +13,8 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library.
 """
 import functools
+import importlib.util
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -129,6 +131,66 @@ def test_campaign_chunk_program_compiles_within_half_of_hbm(one_chip,
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4 * GiB, mem
+
+
+@pytest.mark.parametrize(
+    "tiles", REGISTRY["fail_prob"].tile_space,
+    ids=[f"row_tile{t['row_tile']}" if t else "default"
+         for t in REGISTRY["fail_prob"].tile_space])
+def test_fail_prob_row_sums_compile_for_v5e_at_full_width(one_chip, tiles):
+    """The row-sum mode of ``fail_prob`` at every tile of its registry
+    space: its loops, the row-block slices of the row source and the
+    per-block stores compile through Mosaic at FULL widths."""
+    args, kw = _full_width_args("fail_prob", one_chip)
+    fn = functools.partial(REGISTRY["fail_prob"].pallas, interpret=False,
+                           reduce_rows=True, **tiles, **kw)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _trace_reduce():
+    """``bench/trace_reduce.py``, which names the kernels of a device trace."""
+    path = Path(__file__).resolve().parents[1] / "bench" / "trace_reduce.py"
+    spec = importlib.util.spec_from_file_location("trace_reduce", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_row_lambda_chunk_program_writes_no_cell_grid(one_chip, monkeypatch):
+    """``_row_lambda_impl`` on a FULL chunk of 64 DIMMs sums the cells in
+    the kernel: its temporaries stay under 64 MiB, where one (64, 16, 512,
+    512) f32 grid alone is 1 GiB, and its one Pallas call reads as kernel
+    ``fail_prob`` by the rule the benchmark's trace reduction names kernels
+    by, so the campaign program is still found as the one that runs it."""
+    from repro.core import substrate
+    from repro.core.population import synthetic_fleet
+    from repro.core.streaming import pad_batch
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_platform", lambda: "tpu")
+    batch = pad_batch(synthetic_fleet(2, FULL, seed=0).chunk(0, 2),
+                      CHUNK - 2)
+
+    def a(x):
+        x = np.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    prog = functools.partial(substrate._row_lambda_impl, pidx=2,
+                             iters=DEFAULT_ITERS, internal=False, pallas=True)
+    compiled = jax.jit(prog).lower(
+        jax.tree.map(a, batch),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((4,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((CHUNK,), jnp.float32, sharding=one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem
+    kernel_name = _trace_reduce().kernel_name
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    assert {kernel_name(c) for c in calls} == {"fail_prob"}
 
 
 def test_memsim_grid_program_compiles_for_v5e(one_chip, monkeypatch):
