@@ -240,26 +240,43 @@ def onset_profile(profiles: np.ndarray, min_count: float = 32.0) -> np.ndarray:
     return profiles[-1]
 
 
+def detrend(profile: np.ndarray) -> np.ndarray:
+    """The profile less its least-squares line over the row index: the
+    monotone row-index term taken out, so what is left is the open-bitline
+    V (Fig 3b) with both arms raised above the line.  Computed about the
+    centre row, so a constant profile detrends to exact zeros (ties stay
+    ties)."""
+    p = np.asarray(profile, np.float64)
+    c = np.arange(len(p), dtype=np.float64) - (len(p) - 1) / 2.0
+    dev = p - p.mean()
+    return dev - (float(c @ dev) / float(c @ c)) * c if len(p) > 1 else dev
+
+
 def vulnerable_rows(profile: np.ndarray, k: int = 2,
                     min_sep: int | None = None) -> np.ndarray:
     """The discovered latency test region: ``k`` rows of a canonical internal
-    profile, picked greedily by error count but at least ``min_sep`` rows
-    apart (default R // (2k)).
+    profile, picked greedily by detrended error count (``detrend``) but at
+    least ``min_sep`` rows apart (default R // (2k)), each snapped to the
+    edge of its count plateau.
 
     The separation constraint is what makes the discovery cover *both* arms
     of the open-bitline V (Fig 3b): the monotone row-index term tilts raw
     counts toward one mat edge, so a plain top-k collapses onto adjacent
     rows at that edge — while the other edge hosts the worst cells of the
-    opposite column parity.  Greedy-with-separation lands on both edge rows,
-    i.e. exactly DIVA's design test region, without being told the design.
+    opposite column parity.  Where the V is faint (low-variation dies) the
+    tilt outweighs the far arm itself, and a greedy pick on raw counts lands
+    on the tilted slope R // (2k) rows from the first pick; taking the
+    least-squares line out first leaves the V, whose arms are the mat's two
+    edge rows, i.e. exactly DIVA's design test region, without being told
+    the design.  ``covers_both_arms`` says whether the picks show both arms.
     If the constraint runs out of candidates, the remaining picks fall back
-    to the best unpicked rows.  Ascending row order; count ties break on row
+    to the best unpicked rows.  Ascending row order; ties break on row
     index via the stable sort — deterministic."""
     profile = np.asarray(profile)
     n = len(profile)
     if min_sep is None:
         min_sep = max(1, n // (2 * max(k, 1)))
-    order = np.argsort(-profile, kind="stable")
+    order = np.argsort(-detrend(profile), kind="stable")
     picked: list[int] = []
     for r in order:
         if len(picked) == k:
@@ -277,16 +294,42 @@ def vulnerable_rows(profile: np.ndarray, k: int = 2,
     return np.sort(np.asarray(picked[:k]))
 
 
+def covers_both_arms(profile: np.ndarray, rows) -> bool:
+    """Whether the test rows ``rows`` show both arms of the open-bitline V
+    in ``profile``: the lowest and the highest row lie on opposite sides of
+    the detrended profile's minimum, and each of them, with the row beside
+    it toward that minimum, stands above the least-squares line by more
+    than the Poisson noise floor (``_noise_floor``).  The V's arms are
+    smooth over many rows, so a true arm is never one row wide; a one-row
+    excursion is what Poisson noise makes.  A monotone profile whose far
+    end stays on the line, a noise-only profile, or a one-row region shows
+    one arm at most."""
+    p = np.asarray(profile, np.float64)
+    res = detrend(p)
+    low = int(np.argmin(res))
+    lo, hi = int(np.min(rows)), int(np.max(rows))
+    if not lo < low < hi:
+        return False
+    return all(res[r] > _noise_floor(p[r])
+               for r in (lo, lo + 1, hi - 1, hi))
+
+
+def _noise_floor(count) -> float:
+    """Three Poisson standard deviations of a count: rows whose counts
+    differ by less are the same within noise."""
+    return 3.0 * np.sqrt(max(float(count), 1.0))
+
+
 def _snap_to_plateau_edge(profile: np.ndarray, r: int) -> int:
     """A pick inside a count-saturated plateau is Poisson luck: every row of
     the plateau measured the same (p ~ 1 at the campaign's harshest point),
     so prefer the plateau's address-extreme member — the monotone distance
     terms put the true worst row at the outer end of its arm.  The plateau is
     the contiguous run around ``r`` within the Poisson noise floor
-    (3*sqrt(count)); if it touches an address-space edge, snap there, else
+    (``_noise_floor``); if it touches an address-space edge, snap there, else
     (a genuine interior peak, or a fully flat profile) keep the pick."""
     n = len(profile)
-    tol = 3.0 * np.sqrt(max(float(profile[r]), 1.0))
+    tol = _noise_floor(profile[r])
     lo = r
     while lo > 0 and profile[lo - 1] >= profile[r] - tol:
         lo -= 1

@@ -23,7 +23,8 @@ Serving paths, cheapest first:
   * CONVENTIONAL — no usable signature (zero errors at every campaign
     point), or a signature matching an UNVERIFIED generation (one whose
     founding vote pool was too small or too incoherent to trust the
-    discovered region): the safe every-row sweep.
+    discovered region, or whose region shows one arm of the open-bitline
+    V only): the safe every-row sweep.
 
 Staleness: a table profiled with ``guard_cycles`` cycles of margin stays
 safe until aging drift (``aging_coef`` ns/year — the lifetime model's
@@ -49,7 +50,7 @@ from repro.core.substrate import (_LEAVES, _chunk_jitted, _pad0,
                                   pattern_stress, profile_population_arrays,
                                   row_error_lambda)
 from repro.core.timing import CYCLE_NS, PARAMS
-from repro.discovery.generation import vulnerable_rows
+from repro.discovery.generation import covers_both_arms, vulnerable_rows
 from repro.discovery.recover import (mapping_tables,
                                      recover_mapping_population, vote_mapping)
 from repro.discovery.signatures import (bit_signature_population,
@@ -85,6 +86,14 @@ _M_GENS = _OBS_REGISTRY.gauge(
 _M_REPROF = _OBS_REGISTRY.counter(
     "repro_serve_reprofiled_total", "DIMMs re-profiled by tick()",
     labelnames=("server",))
+# One count per founded generation: region "both_arms" where its test rows
+# show both arms of the open-bitline V (generation.covers_both_arms), else
+# "one_arm"; a one-arm founding is never verified.
+_REGIONS = ("both_arms", "one_arm")
+_M_FOUND = _OBS_REGISTRY.counter(
+    "repro_serve_found_total",
+    "generations founded, by verification and test-region shape",
+    labelnames=("server", "verified", "region"))
 # One span per phase of an ingest chunk or a tick (never per DIMM); a child
 # phase's name starts with its parent's, and every phase span observes its
 # wall time here, so per-phase tails survive untraced runs.
@@ -154,7 +163,8 @@ class FleetConfig:
     onset_min_count: float = 1024.0
     # generation verification: a discovered region is trusted for future
     # hits only when the founding vote pool was large enough and agreed
-    # strongly enough on one scramble (see _discover)
+    # strongly enough on one scramble, and its rows show both arms of the
+    # open-bitline V (see _found)
     consensus_min_share: float = 0.55
     min_founders: int = 4
     # the served operating point (diva_profile defaults)
@@ -208,6 +218,9 @@ class FleetServer:
         self._m_age = _M_AGE.labels(server=self._sid)
         self._m_gens = _M_GENS.labels(server=self._sid)
         self._m_reprof = _M_REPROF.labels(server=self._sid)
+        self._m_found = {(v, reg): _M_FOUND.labels(
+            server=self._sid, verified=str(v).lower(), region=reg)
+            for v in (True, False) for reg in _REGIONS}
         self._m_phase: dict = {}
 
     def _phase(self, name: str, **args):
@@ -356,65 +369,74 @@ class FleetServer:
                     _pad0(sub_counts[t], pad).astype(np.int64), lam))
         has_signal = sub_counts.max(axis=3) > 0            # (T, m, S)
 
-        with self._phase("serve.ingest.discover.vote"):
-            for gen in new_gens:
-                self._found(gen, idx, labels, onset, sub_counts, rec_t,
-                            has_signal, genuine)
+        for gen in new_gens:
+            self._found(gen, idx, labels, onset, sub_counts, rec_t,
+                        has_signal, genuine)
 
     def _found(self, gen, idx, labels, onset, sub_counts, rec_t, has_signal,
                genuine) -> None:
-        """Vote one new generation's scramble from its members' recoveries,
-        read its vulnerable rows off the onset-point canonical profile, and
-        cache them (VERIFIED or not)."""
+        """Vote one new generation's scramble from its members' recoveries
+        (span ``serve.ingest.discover.vote``), then read its vulnerable rows
+        off the onset-point canonical profile and cache them, VERIFIED or
+        not (span ``serve.ingest.discover.region``).  Verification needs a
+        large enough founding pool that agrees on one scramble, and test
+        rows that show both arms of the open-bitline V
+        (``covers_both_arms``): a one-arm region is never trusted."""
         cfg = self.cfg
         R = sub_counts.shape[3]
         T = sub_counts.shape[0]
         nb = self._nbits
         pos = np.flatnonzero(labels[idx] == gen)       # positions in sub
-        vb, vx, vc = [], [], []
-        for t in range(T):
-            keep = has_signal[t][pos].reshape(-1)
-            if not keep.any():
-                continue
-            vb.append(rec_t[t]["ext_bit"][pos].reshape(-1, nb)[keep])
-            vx.append(rec_t[t]["xor"][pos].reshape(-1, nb)[keep])
-            vc.append(rec_t[t]["confidence"][pos].reshape(-1, nb)[keep])
-        if not vb:                                     # nothing observed
-            vb = [rec_t[-1]["ext_bit"][pos[0]]]
-            vx = [rec_t[-1]["xor"][pos[0]]]
-            vc = [rec_t[-1]["confidence"][pos[0]]]
-        vb, vx, vc = (np.concatenate(v) for v in (vb, vx, vc))
-        founder = int(pos[0])
-        t_on = int(onset[idx[founder]])
-        b, x = vote_mapping(vb, vx, vc,
-                            rec_t[t_on]["order_int"][founder, 0])
-        est, i2e = mapping_tables(b, x, R)             # consensus map
-        # generation canonical profile at the onset point, scattered
-        # back through the consensus mapping
-        summed = sub_counts[t_on, pos].sum(axis=(0, 1))  # (R,) external
-        prof = np.zeros(R, np.int64)
-        np.add.at(prof, est, summed)
-        vuln = vulnerable_rows(prof, cfg.k_rows)
-        mass = float(prof[vuln].sum()) / float(max(prof.sum(), 1))
-        # consensus quality: confidence-weighted fraction of the vote
-        # pool that agrees with the voted scramble, per internal bit.
-        # A real generation's members vote coherently (share >~ 0.6);
-        # a cluster of weak-die noise scatters (share <~ 0.5) — and a
-        # tiny pool can be wrong while fully self-consistent, so small
-        # foundings are never trusted regardless of share.
-        agree = (vb == b[None, :]) & (vx == x[None, :])  # (K, nbits)
-        wsum = np.maximum(vc.sum(axis=0), 1e-9)
-        share = (vc * agree).sum(axis=0) / wsum          # per int bit
-        verified = (float(share.mean()) >= cfg.consensus_min_share
-                    and len(pos) >= cfg.min_founders)
-        self.founding_stats[int(gen)] = {
-            "n_founders": int(len(pos)), "region_mass": mass,
-            "conf_mean": float(vc.mean()),
-            "share_mean": float(share.mean()),
-            "share_min": float(share.min()),
-            "all_genuine": bool(genuine[idx[pos]].all()),
-            "verified": verified}
-        self.cache.install(gen, i2e[vuln], verified=verified)
+        with self._phase("serve.ingest.discover.vote"):
+            vb, vx, vc = [], [], []
+            for t in range(T):
+                keep = has_signal[t][pos].reshape(-1)
+                if not keep.any():
+                    continue
+                vb.append(rec_t[t]["ext_bit"][pos].reshape(-1, nb)[keep])
+                vx.append(rec_t[t]["xor"][pos].reshape(-1, nb)[keep])
+                vc.append(rec_t[t]["confidence"][pos].reshape(-1, nb)[keep])
+            if not vb:                                 # nothing observed
+                vb = [rec_t[-1]["ext_bit"][pos[0]]]
+                vx = [rec_t[-1]["xor"][pos[0]]]
+                vc = [rec_t[-1]["confidence"][pos[0]]]
+            vb, vx, vc = (np.concatenate(v) for v in (vb, vx, vc))
+            founder = int(pos[0])
+            t_on = int(onset[idx[founder]])
+            b, x = vote_mapping(vb, vx, vc,
+                                rec_t[t_on]["order_int"][founder, 0])
+            # consensus quality: confidence-weighted fraction of the vote
+            # pool that agrees with the voted scramble, per internal bit.
+            # A real generation's members vote coherently (share >~ 0.6);
+            # a cluster of weak-die noise scatters (share <~ 0.5) — and a
+            # tiny pool can be wrong while fully self-consistent, so small
+            # foundings are never trusted regardless of share.
+            agree = (vb == b[None, :]) & (vx == x[None, :])  # (K, nbits)
+            wsum = np.maximum(vc.sum(axis=0), 1e-9)
+            share = (vc * agree).sum(axis=0) / wsum        # per int bit
+        with self._phase("serve.ingest.discover.region"):
+            est, i2e = mapping_tables(b, x, R)         # consensus map
+            # generation canonical profile at the onset point, scattered
+            # back through the consensus mapping
+            summed = sub_counts[t_on, pos].sum(axis=(0, 1))  # (R,) external
+            prof = np.zeros(R, np.int64)
+            np.add.at(prof, est, summed)
+            vuln = vulnerable_rows(prof, cfg.k_rows)
+            mass = float(prof[vuln].sum()) / float(max(prof.sum(), 1))
+            region = ("both_arms" if covers_both_arms(prof, vuln)
+                      else "one_arm")
+            verified = (float(share.mean()) >= cfg.consensus_min_share
+                        and len(pos) >= cfg.min_founders
+                        and region == "both_arms")
+            self.founding_stats[int(gen)] = {
+                "n_founders": int(len(pos)), "region_mass": mass,
+                "conf_mean": float(vc.mean()),
+                "share_mean": float(share.mean()),
+                "share_min": float(share.min()),
+                "all_genuine": bool(genuine[idx[pos]].all()),
+                "region": region, "verified": verified}
+            self.cache.install(gen, i2e[vuln], verified=verified)
+            self._m_found[verified, region].inc()
 
     # --------------------------------------------------------- profiling
 

@@ -11,6 +11,8 @@ supported row width (hypothesis property, when installed).  End to end:
 ``diva_profile`` timing tables on >= 95% of a 32-DIMM population at the
 default noise level.
 """
+from pathlib import Path
+
 import jax
 import numpy as np
 import pytest
@@ -26,7 +28,8 @@ from repro.discovery import (BlindDiva, bit_signature_population,
                              vote_mapping)
 from repro.discovery.blind import blind_vs_oracle, campaign_counts
 from repro.discovery.generation import (canonical_internal_profiles,
-                                        onset_profile, vulnerable_rows)
+                                        covers_both_arms, onset_profile,
+                                        vulnerable_rows)
 from repro.discovery.recover import mapping_tables
 from repro.sharding import dimm_mesh
 
@@ -265,7 +268,93 @@ def test_vulnerable_rows_never_duplicates_on_shared_plateau():
     profile[63] = 0.0          # plateau reaches row 0 but not row n-1
     rows = vulnerable_rows(profile, 2)
     assert len(set(rows.tolist())) == 2, rows
-    np.testing.assert_array_equal(rows, [0, 40])
+    # row 63's zero tilts the least-squares line down, so the detrended
+    # ranking puts row 62 first (snapped to row 0) and row 61 second, whose
+    # plateau snaps to row 0 too: it keeps its own row
+    np.testing.assert_array_equal(rows, [0, 61])
+
+
+# The onset profiles of the 12 generations that the first 64-DIMM chunk of
+# synthetic_fleet(128, FULL, seed=0) founds under FleetConfig(chunk_size=64)
+# (the canonical profile each founding reads its test rows off), with the
+# founders' design (serial mod 11; A-T founds two generations of 3), and the
+# rows and verification of the raw-count greedy rule that preceded
+# detrending.  B-F, B-M and C-F are the low-variation dies (die scale
+# 0.15-0.22), whose faint V the row-index tilt hid: that rule gave them
+# rows [383, 511].
+with np.load(Path(__file__).parent / "data"
+             / "onset_profiles_full_seed0.npz") as _f:
+    ONSET = dict(_f)
+FAINT = ("B-F", "B-M", "C-F")
+
+
+@pytest.mark.parametrize("g", range(len(ONSET["design"])),
+                         ids=[f"{g}-{d}" for g, d in enumerate(ONSET["design"])])
+def test_onset_profile_regions_cover_both_arms_at_full_geometry(g):
+    profile, design = ONSET["profile"][g], str(ONSET["design"][g])
+    R_full = profile.shape[0]
+    rows = vulnerable_rows(profile, 2)
+    # every region shows both arms, at the mat's two edge rows: the
+    # design-worst rows of the open-bitline V
+    assert covers_both_arms(profile, rows)
+    np.testing.assert_array_equal(rows, [0, R_full - 1])
+    if design in FAINT:
+        np.testing.assert_array_equal(ONSET["raw_greedy_rows"][g], [383, 511])
+        assert not covers_both_arms(profile, ONSET["raw_greedy_rows"][g])
+    else:                      # the strong designs keep the rows they had
+        np.testing.assert_array_equal(rows, ONSET["raw_greedy_rows"][g])
+
+
+def _raw_greedy(profile, k=2):
+    """The rule before detrending: greedy by raw count, R // (2k) apart
+    (the synthetic profiles below have no plateau to snap)."""
+    picked = []
+    for r in np.argsort(-profile, kind="stable"):
+        if all(abs(int(r) - p) >= len(profile) // (2 * k) for p in picked):
+            picked.append(int(r))
+        if len(picked) == k:
+            break
+    return np.sort(picked)
+
+
+_RR = np.arange(512, dtype=np.float64)
+_V4 = (np.maximum(_RR, 511 - _RR) / 511) ** 4      # open-bitline V, 1 at edges
+TILTS = {"exponential": 50.0 * np.exp(_RR / 40.0),  # counts of a steep tail
+         "linear": 100.0 + 1e4 * _RR}
+ARMS = {"exponential": 5e3, "linear": 1e5}         # a faint V on each tilt
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("v", ["faint_v", "no_v"])
+@pytest.mark.parametrize("tilt", sorted(TILTS))
+def test_tilted_profiles_give_both_arms_or_one_arm(tilt, v, seed):
+    """A monotone row-index tilt, a faint V or none, and Poisson noise at
+    R = 512: the raw greedy rule lands on the tilt ([383, 511]); the
+    detrended rule finds both edge rows, or reports one arm (which
+    ``FleetServer`` never verifies)."""
+    lam = TILTS[tilt] + (ARMS[tilt] * _V4 if v == "faint_v" else 0.0)
+    profile = np.random.default_rng(seed).poisson(lam)
+    np.testing.assert_array_equal(_raw_greedy(profile), [383, 511])
+    assert not covers_both_arms(profile, [383, 511])
+    rows = vulnerable_rows(profile, 2)
+    if covers_both_arms(profile, rows):
+        np.testing.assert_array_equal(rows, [0, 511])
+    # a faint V always shows; so does the convex exponential tilt once the
+    # line is out, while a linear tilt without a V leaves noise alone
+    assert covers_both_arms(profile, rows) == (v == "faint_v"
+                                               or tilt == "exponential")
+
+
+def test_covers_both_arms_needs_an_arm_wider_than_one_row():
+    flat = np.full(64, 1000)
+    assert not covers_both_arms(flat, [0, 63])
+    spikes = flat.copy()
+    spikes[[0, 63]] = 2000     # two one-row excursions, a dip between
+    spikes[30] = 0
+    assert not covers_both_arms(spikes, [0, 63])
+    spikes[[1, 62]] = 2000     # arms two rows wide
+    assert covers_both_arms(spikes, [0, 63])
+    assert not covers_both_arms(spikes, [0])     # one row, one arm at most
 
 
 def test_generation_clustering_groups_same_die(campaign):

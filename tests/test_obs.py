@@ -212,7 +212,8 @@ def test_span_observes_into_histogram(registry):
 INGEST_PHASES = {"serve.ingest", "serve.ingest.campaign",
                  "serve.ingest.signature", "serve.ingest.match",
                  "serve.ingest.discover", "serve.ingest.discover.recover",
-                 "serve.ingest.discover.vote", "serve.ingest.route",
+                 "serve.ingest.discover.vote",
+                 "serve.ingest.discover.region", "serve.ingest.route",
                  "serve.ingest.hit_sweep", "serve.ingest.commit"}
 TICK_PHASES = {"serve.tick", "serve.tick.drain", "serve.tick.gather",
                "serve.tick.hit_sweep", "serve.tick.commit"}

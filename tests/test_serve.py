@@ -9,14 +9,18 @@ exactly, labels and deadlines included.
 """
 import dataclasses
 import heapq
+import importlib.util
+from pathlib import Path
 
 import numpy as np
 import pytest
 
+import repro.serve.server as server_mod
 from repro.checkpoint import CheckpointManager
 from repro.core.geometry import TINY
 from repro.core.population import synthetic_fleet
 from repro.core.substrate import profile_population_arrays
+from repro.obs import REGISTRY
 from repro.serve import (PATH_CONVENTIONAL, PATH_DISCOVER, PATH_HIT,
                          FleetConfig, FleetServer, oracle_parity)
 from repro.serve.state import FleetState, GenerationCache
@@ -96,6 +100,80 @@ def test_served_tables_bit_identical_to_oracle(served, fleet):
     is_conv = server.state.view("path") == PATH_CONVENTIONAL
     oracle = np.where(is_conv[:, None], conv, diva)
     np.testing.assert_array_equal(server.state.view("table"), oracle)
+
+
+def _bench_reference():
+    """The benchmark's plain reference of a served table
+    (``bench/reference/fleet.py``, which imports nothing of the program)."""
+    path = (Path(__file__).resolve().parents[1] / "bench" / "reference"
+            / "fleet.py")
+    spec = importlib.util.spec_from_file_location("bench_reference_fleet",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_every_design_served_the_plain_reference_table(served, fleet):
+    """All 11 designs arrive, the three low-variation dies (B-F, B-M, C-F)
+    among them, at TINY, the smallest geometry whose fleet founds
+    generations: every served table equals the plain reference's on the
+    path the server reports, its design-worst rows {0, R-1} or every row.
+    Tolerance 0: a table is a point of the 2.5 ns test grid plus one guard
+    cycle, and both sides make the same counter-hash draws, so they agree
+    exactly or differ by a whole grid step."""
+    server, _ = served
+    ref = _bench_reference()
+    batch = fleet.chunk(0, N)
+    serials = np.asarray(batch.serial)
+    assert len(np.unique(serials % 11)) == 11
+    lv = {k: np.asarray(getattr(batch, k)) for k in ref.LEAVES}
+    cfg, R = server.cfg, TINY.rows_per_mat
+    kw = dict(age_years=0.0, temp_C=cfg.profile_temp_C,
+              refresh_ms=cfg.profile_refresh_ms,
+              guard_cycles=cfg.guard_cycles, multibit=cfg.multibit_only,
+              cols=TINY.cols_per_mat, mats=TINY.mats_x, chips=TINY.chips,
+              block=N)
+    worst = ref.tables(lv, ref.design_worst_rows(R), **kw)
+    every = ref.tables(lv, np.arange(R), **kw)
+    rows = server.state.rows_for(serials)
+    is_conv = server.state.view("path")[rows] == PATH_CONVENTIONAL
+    np.testing.assert_array_equal(server.state.view("table")[rows],
+                                  np.where(is_conv[:, None], every, worst))
+
+
+def _found_counts(server) -> dict:
+    fam = REGISTRY.get("repro_serve_found_total")
+    return {(v, r): fam.labels(server=server._sid, verified=v,
+                               region=r).value()
+            for v in ("true", "false") for r in ("both_arms", "one_arm")}
+
+
+def test_found_counter_moves_at_each_founding(served):
+    """``repro_serve_found_total{verified, region}`` counts every founding
+    by its outcome, and a one-arm founding is never verified."""
+    server, _ = served
+    tally = {k: 0 for k in _found_counts(server)}
+    for st in server.founding_stats.values():
+        tally[str(st["verified"]).lower(), st["region"]] += 1
+        assert st["region"] == "both_arms" or not st["verified"]
+    assert _found_counts(server) == tally
+    assert sum(tally.values()) == server.cache.n_generations > 0
+
+
+def test_one_arm_founding_is_never_verified(fleet, monkeypatch):
+    """Where the founding profile shows one arm of the V, the generation is
+    installed unverified whatever its vote, and its members, founders
+    included, take the every-row sweep."""
+    monkeypatch.setattr(server_mod, "covers_both_arms", lambda p, rows: False)
+    server = FleetServer(fleet, FleetConfig(chunk_size=CHUNK))
+    stats = server.ingest(CHUNK, now=0.0)
+    n = stats["n_generations"]
+    assert n > 0 and stats["conventional"] == CHUNK
+    assert not any(server.cache.verified(g) for g in range(n))
+    counts = _found_counts(server)
+    assert counts.pop(("false", "one_arm")) == n
+    assert set(counts.values()) == {0}
 
 
 # ------------------------------------------------------------------- queries
