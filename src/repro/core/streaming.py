@@ -58,7 +58,7 @@ from repro.core.substrate import (DimmBatch, _LEAVES, _axis_context,
                                   _run_sharded, _shuffling_impl,
                                   condition_adders, donation_enabled,
                                   lifetime_adders, operating_grid_tables,
-                                  pattern_stress)
+                                  pattern_stress, sweep_steps)
 from repro.core.timing import PARAMS, VDD_STD
 from repro.obs import REGISTRY as _OBS_REGISTRY
 from repro.sharding import chunk_spans
@@ -400,9 +400,11 @@ def stream_profile_population(source, *, chunk_size: int = 1024,
         if ctx_d is not None:
             args = args + (ctx_d, ctx_g)
             donate, argnums = (0, 3, 4), (0, 3, 4)
-        tables = _chunk_call("stream_profile", _profile_impl, args, statics,
-                             donate=donate, batch_argnums=argnums, mesh=mesh)
+        tables, steps = _chunk_call("stream_profile", _profile_impl, args,
+                                    statics, donate=donate,
+                                    batch_argnums=argnums, mesh=mesh)
         tables = np.asarray(tables if banks > 1 else tables[:, 0])
+        sweep_steps("stream_profile", steps)
         return {name: tables for name in red}
 
     return stream_population(stream, program, red,

@@ -11,9 +11,9 @@ repair-resolved row-source tables, scramble tables — built once from
   * ``row_error_lambda``   — expected per-row error counts for the whole
                              population in one jitted call (Figs 6/7/14).
   * ``profile_population`` — DIVA / conventional profiling of every DIMM as a
-                             single jitted ``lax.scan`` over the timing grid
-                             (Sec 6.1); no Python loop over DIMMs, subarrays
-                             or patterns.
+                             single jitted program (Sec 6.1): an early-exit
+                             loop down each timing grid; no Python loop over
+                             DIMMs, subarrays or patterns.
   * ``lifetime_population`` — the whole online-profiling *lifecycle* (Sec 6.1
                              fn 2): one jitted ``lax.scan`` over profiling
                              epochs, applying host-precomputed aging-drift and
@@ -513,25 +513,39 @@ def _op_region_eval(batch: DimmBatch, t_subs, rows, stress, adder, extra,
 def _sweep_param(batch: DimmBatch, pidx: int, floor, rows, stress, adder,
                  guard_cycles: int, iters: int, multibit: bool,
                  banks: int = 1, extra=None):
-    """lax.scan down one parameter's timing grid; per-(DIMM, bank) min-safe
-    value (``floor`` is (D, banks)).
+    """Walk one parameter's timing grid; per-(DIMM, bank) min-safe value
+    (``floor`` is (D, banks)) and the number of grid points evaluated.
 
     Reproduces the legacy walker: stop at the first grid point that fails or
-    undercuts the floor, keep the last safe value, add the guardband.
+    undercuts the floor, keep the last safe value, add the guardband.  A
+    table depends only on the grid prefix before each DIMM's first stop, so
+    the ``lax.while_loop`` ends at the first point where every (DIMM, bank)
+    has stopped or lies below its floor: the points it skips are the ones
+    whose results a full scan would discard.
     """
     grid = jnp.asarray(GRIDS[PARAMS[pidx]], jnp.float32)
     std = getattr(STANDARD, PARAMS[pidx])
+    G = grid.shape[0]
 
-    def step(_, t_op):
+    def running(carry):
+        i, stopped, _ = carry
+        below = grid[jnp.minimum(i, G - 1)] < floor - 1e-9
+        return (i < G) & ~jnp.all(stopped | below)
+
+    def step(carry):
+        i, stopped, best = carry
+        t_op = grid[i]
         fail, _ = _region_eval(batch, pidx, t_op, rows, stress, adder,
                                iters, multibit, banks, extra)
-        return None, fail | (t_op < floor - 1e-9)
+        stopped = stopped | fail | (t_op < floor - 1e-9)
+        best = jnp.where(stopped, best, jnp.minimum(best, t_op))
+        return i + 1, stopped, best
 
-    _, stops = jax.lax.scan(step, None, grid)            # (G, D, banks)
-    ok = jnp.cumsum(stops.astype(jnp.int32), axis=0) == 0
-    best = jnp.min(jnp.where(ok, grid[:, None, None], jnp.inf), axis=0)
+    init = (jnp.int32(0), jnp.zeros(floor.shape, bool),
+            jnp.full(floor.shape, jnp.inf, jnp.float32))
+    n_steps, _, best = jax.lax.while_loop(running, step, init)
     best = jnp.where(jnp.isfinite(best), best, std)
-    return jnp.minimum(best + guard_cycles * CYCLE_NS, std)
+    return jnp.minimum(best + guard_cycles * CYCLE_NS, std), n_steps
 
 
 def _sweep_axis(batch: DimmBatch, axis: str, t_subs, rows, stress,
@@ -579,7 +593,10 @@ def _profile_impl(batch: DimmBatch, rows, stress, adder, ctx_d=None,
     timing via ``_sweep_axis``.  Returns (D, banks, len(axes)): per-bank
     tables when ``banks > 1`` (each bank's sweep sees only its own
     subarrays' failures, so a bank can settle below the whole-DIMM value —
-    the FLY-DRAM margin), the whole-DIMM sweep at ``banks=1``.
+    the FLY-DRAM margin), the whole-DIMM sweep at ``banks=1``.  Also returns
+    (D, 4) int32: the grid points each timing parameter's loop evaluated,
+    the same in every row (DIMM-leading so the padded and sharded routes
+    slice it like the tables; ``sweep_steps`` reads it on the host).
 
     ``ctx_d``/``ctx_g`` carry the HOST-precomputed per-axis tables
     (``_axis_context``): ctx_d's leaves are DIMM-leading (sharded with the
@@ -597,11 +614,12 @@ def _profile_impl(batch: DimmBatch, rows, stress, adder, ctx_d=None,
               guard_cycles=guard_cycles, iters=iters, multibit=multibit,
               extra=extra)
     floor5 = jnp.full((D, banks), 5.0, jnp.float32)
-    res = {}
-    res["trcd"] = trcd = _sweep_param(batch, 0, floor5, **kw)
-    res["tras"] = _sweep_param(batch, 1, trcd + 10.0, **kw)
-    res["trp"] = _sweep_param(batch, 2, floor5, **kw)
-    res["twr"] = _sweep_param(batch, 3, floor5, **kw)
+    res, steps = {}, {}
+    res["trcd"], steps["trcd"] = _sweep_param(batch, 0, floor5, **kw)
+    res["tras"], steps["tras"] = _sweep_param(batch, 1, res["trcd"] + 10.0,
+                                              **kw)
+    res["trp"], steps["trp"] = _sweep_param(batch, 2, floor5, **kw)
+    res["twr"], steps["twr"] = _sweep_param(batch, 3, floor5, **kw)
     extra_axes = tuple(axes[len(PARAMS):])
     if extra_axes:
         std_t = jnp.asarray([getattr(STANDARD, p) for p in PARAMS],
@@ -624,7 +642,9 @@ def _profile_impl(batch: DimmBatch, rows, stress, adder, ctx_d=None,
                 batch, ax, t_subs, rows, stress, extras_gd, adders_gd,
                 ctx_g[f"{ax}_keys"], ctx_g[f"{ax}_retx"], guard_cycles,
                 iters, multibit, banks, retention)
-    return jnp.stack([res[a] for a in axes], axis=2)
+    n_steps = jnp.stack([steps[p] for p in PARAMS])
+    return (jnp.stack([res[a] for a in axes], axis=2),
+            jnp.broadcast_to(n_steps[None, :], (D, len(PARAMS))))
 
 
 _profile_jit = functools.partial(
@@ -714,6 +734,27 @@ _OBS_REUSES = _OBS_REGISTRY.counter(
     "repro_compile_reuse_total",
     "compiled-program cache hits by (cache, entry point)",
     labelnames=("cache", "entry"))
+# Sweep work: per call of a profiling program and per timing parameter, the
+# grid points its early-exit loop evaluated and those it skipped (evaluated
+# + skipped = the grid's length), read off the program's step output.
+_OBS_SWEEP_STEPS = _OBS_REGISTRY.counter(
+    "repro_sweep_grid_steps_total",
+    "timing-grid points of the profiling sweep, evaluated or skipped",
+    labelnames=("entry", "param", "outcome"))
+
+
+def sweep_steps(entry: str, steps) -> None:
+    """Count one profiling call's grid points from ``_profile_impl``'s
+    (D, 4) step output.  Every row holds its loop's count; under a mesh
+    each shard ran its own loop, and the longest one is counted."""
+    n = np.asarray(steps).max(axis=0)
+    for p, k in zip(PARAMS, n):
+        k, size = int(k), len(GRIDS[p])
+        _OBS_SWEEP_STEPS.labels(entry=entry, param=p,
+                                outcome="evaluated").inc(k)
+        _OBS_SWEEP_STEPS.labels(entry=entry, param=p,
+                                outcome="skipped").inc(size - k)
+
 
 _CHUNK_JIT_CACHE: dict = {}
 
@@ -873,9 +914,10 @@ def profile_population_arrays(batch: DimmBatch, *, region: str = "worst",
     statics = dict(guard_cycles=guard_cycles, iters=iters,
                    multibit=multibit_only, banks=banks, axes=axes,
                    retention=retention)
-    out = _dispatch("profile", mesh, _profile_impl, _profile_jit, args,
-                    statics, batch_argnums=argnums)
+    out, steps = _dispatch("profile", mesh, _profile_impl, _profile_jit,
+                           args, statics, batch_argnums=argnums)
     out = np.asarray(out)
+    sweep_steps("profile", steps)
     return out[:, 0] if banks == 1 else out
 
 
@@ -983,7 +1025,7 @@ def _lifetime_impl(batch: DimmBatch, rows, stress, adders_dl, ctx_d=None,
               ctx_d=ctx_d, ctx_g=ctx_g, axes=axes, retention=retention)
 
     def epoch(prev_t, adder):
-        t_new = _profile_impl(batch, adder=adder, **kw)  # (D, banks, n_axes)
+        t_new, _ = _profile_impl(batch, adder=adder, **kw)  # (D, banks, n_axes)
         if not diagnostics:
             return t_new, (t_new,)
         stale = jnp.zeros((D, banks), bool)

@@ -48,7 +48,7 @@ from repro.core.streaming import as_stream, hash_poisson_counts, pad_batch
 from repro.core.substrate import (_LEAVES, _chunk_jitted, _pad0,
                                   _profile_impl, lifetime_adders,
                                   pattern_stress, profile_population_arrays,
-                                  row_error_lambda)
+                                  row_error_lambda, sweep_steps)
 from repro.core.timing import CYCLE_NS, PARAMS
 from repro.discovery.generation import covers_both_arms, vulnerable_rows
 from repro.discovery.recover import (mapping_tables,
@@ -472,11 +472,13 @@ class FleetServer:
             padded = pad_batch(batch, pad)
             rows = _pad0(np.asarray(internal_rows, np.int32), pad)
             adder = self._adder(padded, now)
-            out = _chunk_jitted("serve_profile", _profile_impl, self._statics,
-                                donate=(0, 3))(padded, jnp.asarray(rows),
-                                               self._stress,
-                                               jnp.asarray(adder))
-            return np.array(out, np.float32)[:n, 0]
+            out, steps = _chunk_jitted(
+                "serve_profile", _profile_impl, self._statics,
+                donate=(0, 3))(padded, jnp.asarray(rows), self._stress,
+                               jnp.asarray(adder))
+            tables = np.array(out, np.float32)[:n, 0]
+            sweep_steps("serve_profile", steps)
+            return tables
 
     def _profile_all_rows(self, batch, now: float,
                           prefix: str = "serve.ingest") -> np.ndarray:

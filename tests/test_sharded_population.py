@@ -7,6 +7,8 @@ A single-device mesh runs the same shard_map program and is tested
 unconditionally; true multi-device parity (including the padding path for
 D % n_devices != 0) runs when the runtime exposes > 1 device — CI forces this
 with XLA_FLAGS=--xla_force_host_platform_device_count=2."""
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -14,10 +16,12 @@ import pytest
 from repro.core import shuffling
 from repro.core.geometry import SMALL
 from repro.core.population import make_population
-from repro.core.substrate import (DimmBatch, fail_prob_grids,
+from repro.core.substrate import (GRIDS, DimmBatch, fail_prob_grids,
                                   lifetime_population,
                                   profile_population_arrays, row_error_lambda,
                                   shuffling_gain_population)
+from repro.core.timing import PARAMS
+from repro.obs import REGISTRY
 from repro.sharding import dimm_mesh
 
 POP = make_population(SMALL, 6)
@@ -55,6 +59,46 @@ def test_profile_population_sharded_parity_with_padding():
     sub = DimmBatch.from_population(POP[:n + 1])
     ref = profile_population_arrays(sub, temp_C=85.0)
     out = profile_population_arrays(sub, temp_C=85.0, mesh=dimm_mesh())
+    np.testing.assert_array_equal(ref, out)
+
+
+def _grid_steps():
+    """Grid points counted per parameter for the entry ``profile``."""
+    return {p: sum(REGISTRY.value("repro_sweep_grid_steps_total",
+                                  entry="profile", param=p, outcome=o)
+                   for o in ("evaluated", "skipped"))
+            for p in PARAMS}
+
+
+@pytest.mark.parametrize("age", [0.0, 20.0, 80.0])
+def test_profile_early_exit_sharded_parity(age):
+    """Under shard_map each shard runs its own early-exit loop over its own
+    DIMMs: the tables are the unsharded ones, and each call counts one
+    grid per parameter, evaluated or skipped."""
+    aged = dataclasses.replace(
+        BATCH, age_years=np.full(BATCH.n_dimms, age, np.float32))
+    kw = dict(temp_C=55.0, multibit_only=True, banks=2)
+    ref = profile_population_arrays(aged, **kw)
+    for mesh in _meshes():
+        before = _grid_steps()
+        out = profile_population_arrays(aged, mesh=mesh, **kw)
+        after = _grid_steps()
+        np.testing.assert_array_equal(ref, out, err_msg=str(mesh))
+        for p, n in after.items():
+            assert n - before[p] == len(GRIDS[p]), (p, mesh)
+
+
+@multidevice
+def test_profile_early_exit_sharded_parity_with_padding():
+    """Shards whose loops end at different points: one DIMM that never
+    fails keeps its shard's loops running to the floor, the others stop."""
+    n = jax.device_count()
+    sub = DimmBatch.from_population(POP[:n + 1])
+    base = np.array(sub.base)
+    base[0] = -50.0
+    sub = dataclasses.replace(sub, base=base)
+    ref = profile_population_arrays(sub, temp_C=55.0)
+    out = profile_population_arrays(sub, temp_C=55.0, mesh=dimm_mesh())
     np.testing.assert_array_equal(ref, out)
 
 
