@@ -25,11 +25,11 @@ bit-identical outputs — the measured reason the CPU default is the oracle.
 
     PYTHONPATH=src python benchmarks/kernel_bench.py --smoke
 
-``--bench-kernels`` times the nine registry dispatch sites under every
+``--bench-kernels`` times the eight registry dispatch sites under every
 backend route available on this host (``ops.valid_tags()``) and appends one
 row per (kernel, backend) to ``benchmarks/BENCH_kernels.json`` — the
 committed per-backend kernel trajectory (``run.py --check`` validates the
-schema and that every backend covers all nine kernels):
+schema and that every backend covers all eight kernels):
 
     PYTHONPATH=src python benchmarks/kernel_bench.py --bench-kernels
 
@@ -77,9 +77,10 @@ def _bench(fn, *args, iters=3, **kw):
 
 
 def kernel_cases():
-    """The nine registry dispatch sites as benchable cases, in registry
+    """The eight registry dispatch sites as benchable cases, in registry
     order: ``(kernel, shape, call)`` where ``call()`` runs the dispatch site
-    once on fixed inputs under whatever backend is ambient.  One list feeds
+    once on fixed inputs under whatever backend is ambient (``fail_prob``
+    twice: the access channel, and with both channels).  One list feeds
     the legacy CSV dict (``kernels``), the committed per-backend trajectory
     (``bench_kernels``) and the route gate (``kernel_route_smoke``), so none
     of them can drift out of sync with ``kernels/registry.py``."""
@@ -119,7 +120,7 @@ def kernel_cases():
         ("secded_syndrome", "4096w", ops.secded_syndrome, (code,), {}),
         ("fail_prob", "8x512x128", ops.fail_prob,
          (row_src, d_mat, coeffs), {"cols": 128}),
-        ("fail_prob_op", "8x512x128", ops.fail_prob_op,
+        ("fail_prob", "8x512x128_2ch", ops.fail_prob,
          (row_src, d_mat, op_coeffs),
          {"cols": 128, "voltage": True, "retention": True}),
         ("bit_signature", "4096x512", ops.bit_signature,
@@ -160,7 +161,7 @@ def _bench_case(fn, args, kw, iters=3):
 
 
 def kernels(tag: str | None = None):
-    """Legacy flat CSV dict ``{f"{kernel}_{shape}_us": us}`` over the nine
+    """Legacy flat CSV dict ``{f"{kernel}_{shape}_us": us}`` over the eight
     registry sites (plus the unshuffled-layout permutation, which rides the
     ``diva_shuffle`` site); ``tag`` pins the backend route via
     ``ops.force_backend`` (None = the ambient ``backend_tag()``)."""
@@ -198,20 +199,20 @@ def bench_kernels(out_path: Path, tags: tuple[str, ...] | None = None,
     cases = kernel_cases()
     ref_us = {}
     with ops.force_backend(tags[0]):
-        for name, _, fn, a, kw in cases:
-            ref_us[name] = _bench_case(fn, a, kw, iters=iters)[0]
+        for name, shape, fn, a, kw in cases:
+            ref_us[name, shape] = _bench_case(fn, a, kw, iters=iters)[0]
     date = time.strftime("%Y-%m-%d")
     rows = []
     for tag in tags:
         with ops.force_backend(tag):
             for name, shape, fn, a, kw in cases:
-                us = ref_us[name] if tag == tags[0] \
+                us = ref_us[name, shape] if tag == tags[0] \
                     else _bench_case(fn, a, kw, iters=iters)[0]
                 rows.append({
                     "date": date, "backend": tag, "kernel": name,
                     "shape": shape, "us_per_call": round(us, 1),
                     "speedup_vs_ref":
-                    round(ref_us[name] / max(us, 1e-9), 3)})
+                    round(ref_us[name, shape] / max(us, 1e-9), 3)})
     history = []
     if out_path.exists():
         history = json.loads(out_path.read_text())

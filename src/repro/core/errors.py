@@ -237,8 +237,9 @@ class DimmModel:
         """Lazily yield (sub, pat_idx, lam) for one full operating point:
         the access channel summed over ALL four timing parameters at the
         point's table values plus (optionally) the retention channel — the
-        per-point loop reference for ``substrate._op_region_eval`` (same
-        float32 op order, modulo reduction-order ulps)."""
+        per-point loop reference for ``substrate._region_eval`` at a full
+        operating point (same float32 op order, modulo reduction-order
+        ulps)."""
         g = self.geom
         R = g.rows_per_mat
         shift = access_vdd_shift(self.vendor.vdd_coef, op.vdd)

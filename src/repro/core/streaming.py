@@ -53,7 +53,7 @@ from repro.core.packing import narrow_counts, pack_bool
 from repro.core.substrate import (DimmBatch, _LEAVES, _axis_context,
                                   _chunk_jitted, _geom_consts, _lifetime_impl,
                                   _mesh_key, _op_grid_impl, _pack_coeffs,
-                                  _pack_op_coeffs, _pad0, _profile_impl,
+                                  _pad0, _profile_impl,
                                   _resolve_rows, _row_lambda_impl,
                                   _run_sharded, _shuffling_impl,
                                   condition_adders, donation_enabled,
@@ -555,16 +555,12 @@ def _error_summary_impl(row_src, d_mat, coeffs, keep, *,
     device; what crosses to host is per-DIMM scalars, the fleet cell-sum,
     exact per-cell hot counts, and a bit-packable per-DIMM row fail map.
     ``keep`` masks clone-padding out of the cross-DIMM aggregates.  Static
-    ``voltage``/``retention`` route through the operating-point kernel
-    (15-coefficient rows); both off is the plain ``fail_prob`` graph."""
+    ``voltage``/``retention`` add the kernel's supply shift and retention
+    channel (15-coefficient rows); both off is the plain 9-wide graph."""
     from repro.kernels import ops
-    if voltage or retention:
-        grids = ops.fail_prob_op_batch(row_src, d_mat, coeffs, cols=cols,
-                                       voltage=voltage, retention=retention,
-                                       pallas=pallas)       # (C, M, R, cols)
-    else:
-        grids = ops.fail_prob_batch(row_src, d_mat, coeffs, cols=cols,
-                                    pallas=pallas)          # (C, M, R, cols)
+    grids = ops.fail_prob_batch(row_src, d_mat, coeffs, cols=cols,
+                                voltage=voltage, retention=retention,
+                                pallas=pallas)              # (C, M, R, cols)
     keep4 = keep[:, None, None, None]
     return {
         "lam_total": grids.sum(axis=(1, 2, 3)),             # (C,) per-DIMM
@@ -629,8 +625,8 @@ def stream_error_summary(source, param: str, t_op: float, *,
       * ``fail_maps`` (opt-in) — per-DIMM (R,) row fail maps, bit-packed
         8 cells/byte (``packing.pack_bool``) before they go resident.
 
-    ``vdd`` / ``retention`` route the chunk program through the
-    operating-point kernel (``ops.fail_prob_op_batch``): a non-nominal
+    ``vdd`` / ``retention`` set the kernel's channel flags
+    (``ops.fail_prob_batch(voltage=, retention=)``): a non-nominal
     supply shifts the access channel, and ``retention=True`` adds the
     refresh/temperature retention channel riding the swept parameter's
     design-variation sum (canonically ``param="tras"``, the charge-restore
@@ -658,14 +654,10 @@ def stream_error_summary(source, param: str, t_op: float, *,
 
     def program(batch, keep, lo):
         adder = jnp.asarray(condition_adders(batch, temp_C, refresh_ms))
-        if voltage or retention:
-            shift = access_vdd_shift(
-                np.asarray(batch.vdd_coef, np.float32), vdd)
-            coeffs = _pack_op_coeffs(batch, pidx, np.float32(t_op), stress,
-                                     adder, chip, subarray, shift, ret_x)
-        else:
-            coeffs = _pack_coeffs(batch, pidx, np.float32(t_op), stress,
-                                  adder, chip, subarray)
+        shift = (access_vdd_shift(np.asarray(batch.vdd_coef, np.float32), vdd)
+                 if voltage or retention else None)
+        coeffs = _pack_coeffs(batch, pidx, np.float32(t_op), stress, adder,
+                              chip, subarray, shift, ret_x)
         args = (jnp.asarray(batch.row_src[:, subarray]), d_mat, coeffs,
                 jnp.asarray(keep))
         # hand-rolled dispatch (mixed out-specs) — count the chunk here

@@ -67,6 +67,8 @@ if TYPE_CHECKING:  # avoid an import cycle: errors.py imports query_uniform
 # legacy call sites; GRIDS covers every operating-point axis.
 TIMING_GRIDS = {p: AXES[p].grid for p in PARAMS}
 GRIDS = dict(TIMING_GRIDS, vdd=AXES["vdd"].grid, refresh=AXES["refresh"].grid)
+# every timing parameter at once: the ``pidx`` of a full operating point
+_TIMING_IDX = tuple(range(len(PARAMS)))
 
 
 # ----------------------------------------------------------------- hashing
@@ -300,9 +302,9 @@ def condition_adders(batch: DimmBatch, temp_C: float,
 
 # ------------------------------------------------- region failure decisions
 
-def _region_eval(batch: DimmBatch, pidx: int, t_op, rows, stress,
+def _region_eval(batch: DimmBatch, pidx, t_op, rows, stress,
                  adder, iters: int, multibit: bool, banks: int = 1,
-                 extra=None):
+                 extra=None, lane=None, key=None, ret_x=None):
     """Monte-Carlo region test of the whole batch at one operating point.
 
     Returns ``(fails, lam_total)``: (D, banks) bool — does the row region fail
@@ -333,101 +335,17 @@ def _region_eval(batch: DimmBatch, pidx: int, t_op, rows, stress,
     pre-operating-point one — the same bit-parity trick as ``banks=1``.
     The hash never keys on conditions (temp/refresh/vdd context), so context
     changes move lambdas, never draws — the monotonicity sweeps lean on.
-    """
-    g = batch.geom
-    R, C, S = g.rows_per_mat, g.cols_per_mat, g.subarrays
-    assert S % banks == 0, (S, banks)
-    subs_per_bank = S // banks
-    chips = g.chips
-    d_wl, d_mat, even = _geom_consts(g)
 
-    base = batch.base[:, pidx]
-    kbl, kwl = batch.k_bl[:, pidx], batch.k_wl[:, pidx]
-    kmat, krow = batch.k_mat[:, pidx], batch.k_row[:, pidx]
-    chip0 = batch.chip_offsets[:, 0]
-    t_op = jnp.asarray(t_op, jnp.float32)
-    t_q = jnp.round(t_op * 4).astype(jnp.uint32)
-    per_sub_t = t_op.ndim == 2
-    per_dimm_t = t_op.ndim == 1
-    if per_dimm_t:
-        t_cell_all, t_hash_all = t_op[:, None, None, None, None], t_q[:, None]
-    elif not per_sub_t:
-        t_cell_all, t_hash_all = t_op, t_q
-    P = stress.shape[0]
-    pat_idx = jnp.arange(P)[None, :]
-    bank_ids = jnp.arange(banks)
-
-    def per_subarray(acc, s):
-        fails_acc, lam_acc = acc
-        if per_sub_t:                                    # (D, S) tables
-            t_cell = t_op[:, s][:, None, None, None, None]
-            t_hash = t_q[:, s][:, None]
-        else:
-            t_cell, t_hash = t_cell_all, t_hash_all
-        row_src_s = jnp.take(batch.row_src, s, axis=1)   # (D, R)
-        if rows.ndim == 2:                               # per-DIMM regions
-            rsel = jnp.take_along_axis(row_src_s, rows, axis=1)
-        else:
-            rsel = jnp.take(row_src_s, rows, axis=1)
-        rf = rsel.astype(jnp.float32)                    # (D, Rr)
-        d_bl = jnp.where(even[None, None, :], rf[:, :, None],
-                         (R - 1) - rf[:, :, None]) / (R - 1)   # (D,Rr,C)
-        d_row = rf / (R - 1)
-        var = (kbl[:, None, None, None] * d_bl[:, None, :, :]
-               + kwl[:, None, None, None] * d_wl[None, None, None, :]
-               + kmat[:, None, None, None] * d_mat[None, :, None, None]
-               + krow[:, None, None, None] * d_row[:, None, :, None])
-        t = base[:, None, None, None, None] + stress[None, :, None, None, None] \
-            * var[:, None, :, :, :]                      # (D,P,M,Rr,C)
-        t = t + adder[:, None, None, None, None]
-        if extra is not None:
-            t = t + extra[:, None, None, None, None]
-        t = t + chip0[:, None, None, None, None]
-        t = t + jnp.take(batch.sub_offsets, s, axis=1)[:, None, None, None, None]
-        p = fail_mixture(t, t_cell, batch.sigma[:, None, None, None, None],
-                         batch.outlier_rate[:, None, None, None, None],
-                         batch.outlier_ns[:, None, None, None, None], xp=jnp)
-        if multibit:
-            p_multi = multibit_tail(p, xp=jnp)
-            lam = jnp.maximum(
-                2 * iters * chips * p_multi.sum(axis=(2, 3, 4)) / 72.0, 0.0)
-        else:
-            lam = 2 * iters * chips * p.sum(axis=(2, 3, 4))   # (D,P)
-        u = query_uniform(batch.serial[:, None], pidx, t_hash, int(multibit),
-                          s, pat_idx, xp=jnp)
-        fail_s = jnp.any(u < -jnp.expm1(-lam), axis=1)   # (D,)
-        bank_oh = bank_ids == s // subs_per_bank         # (banks,)
-        fails_acc = fails_acc | (fail_s[:, None] & bank_oh[None, :])
-        lam_acc = lam_acc + lam.sum(axis=1)[:, None] \
-            * bank_oh.astype(jnp.float32)[None, :]
-        return (fails_acc, lam_acc), None
-
-    D = batch.serial.shape[0]
-    init = (jnp.zeros((D, banks), bool), jnp.zeros((D, banks), jnp.float32))
-    (fails, lam_total), _ = jax.lax.scan(per_subarray, init, jnp.arange(S))
-    return fails, lam_total
-
-
-def _op_region_eval(batch: DimmBatch, t_subs, rows, stress, adder, extra,
-                    lane: int, key_q, iters: int, multibit: bool,
-                    banks: int, retention: bool, ret_x):
-    """Monte-Carlo region test of the whole batch at one *operating point*.
-
-    Where ``_region_eval`` tests ONE timing knob against one candidate
-    value, this evaluates a full point: every timing parameter at its
-    (D, S, 4) per-subarray table value, plus (static ``retention``) the
-    retention error channel, with a single accept/reject draw per
-    (subarray, pattern).  The draw is keyed on ``(lane, key_q)`` — the
-    swept axis's hash lane and quantized value (or the folded
-    ``timing.op_point_key`` on ``OP_GRID_LANE`` for cross-product grids) —
-    and NEVER on the ambient conditions, so draws are chunking/sharding
-    invariant and single-axis sweeps stay monotone in lambda.
-
-    ``extra`` is the (D,) access-channel voltage shift (or None);
-    ``ret_x`` a traced f32 retention-stress scalar (ignored unless
-    ``retention``).  Returns ``(fails, lam)`` shaped (D, banks) exactly
-    like ``_region_eval``; lam sums the access channel over the four
-    timing parameters plus the retention channel.
+    A *full operating point* passes a tuple ``pidx`` of timing parameters
+    with a (D, S, len(pidx)) ``t_op`` table: each parameter's access channel
+    is tested at its own column, and the channel lambdas add into the one
+    accept/reject draw per (subarray, pattern).  ``ret_x`` (a traced f32
+    retention-stress scalar) adds the retention channel, driven by tRAS's
+    variation sum.  The draw is keyed on ``(lane, key)``: by default the
+    parameter and the quantized ``t_op``; the operating-point sweeps pass
+    the swept axis's hash lane and quantized value (or the folded
+    ``timing.op_point_key`` on ``OP_GRID_LANE``), so draws stay
+    chunking/sharding invariant and single-axis sweeps monotone in lambda.
     """
     g = batch.geom
     R, S = g.rows_per_mat, g.subarrays
@@ -435,12 +353,21 @@ def _op_region_eval(batch: DimmBatch, t_subs, rows, stress, adder, extra,
     subs_per_bank = S // banks
     chips = g.chips
     d_wl, d_mat, even = _geom_consts(g)
+
+    pids = (pidx,) if isinstance(pidx, int) else tuple(pidx)
+    coefs = [(batch.base[:, p], batch.k_bl[:, p], batch.k_wl[:, p],
+              batch.k_mat[:, p], batch.k_row[:, p]) for p in pids]
     chip0 = batch.chip_offsets[:, 0]
+    t_op = jnp.asarray(t_op, jnp.float32)
+    if key is None:
+        lane, key = pidx, jnp.round(t_op * 4).astype(jnp.uint32)
+    else:
+        key = jnp.asarray(key, jnp.uint32)
+    t_cell_all = t_op[:, None, None, None, None] if t_op.ndim == 1 else t_op
+    key_all = key[:, None] if key.ndim == 1 else key
     P = stress.shape[0]
     pat_idx = jnp.arange(P)[None, :]
     bank_ids = jnp.arange(banks)
-    key_q = jnp.asarray(key_q, jnp.uint32)
-    D = batch.serial.shape[0]
 
     def channel_lam(pr):
         if multibit:
@@ -451,42 +378,48 @@ def _op_region_eval(batch: DimmBatch, t_subs, rows, stress, adder, extra,
 
     def per_subarray(acc, s):
         fails_acc, lam_acc = acc
-        row_src_s = jnp.take(batch.row_src, s, axis=1)       # (D, R)
-        if rows.ndim == 2:
+        if t_op.ndim == 3:                               # (D, S, n) tables
+            t_cells = [t_op[:, s, k][:, None, None, None, None]
+                       for k in range(len(pids))]
+        elif t_op.ndim == 2:                             # (D, S) tables
+            t_cells = [t_op[:, s][:, None, None, None, None]]
+        else:
+            t_cells = [t_cell_all]
+        key_s = key[:, s][:, None] if key.ndim == 2 else key_all
+        row_src_s = jnp.take(batch.row_src, s, axis=1)   # (D, R)
+        if rows.ndim == 2:                               # per-DIMM regions
             rsel = jnp.take_along_axis(row_src_s, rows, axis=1)
         else:
             rsel = jnp.take(row_src_s, rows, axis=1)
-        rf = rsel.astype(jnp.float32)                        # (D, Rr)
+        rf = rsel.astype(jnp.float32)                    # (D, Rr)
         d_bl = jnp.where(even[None, None, :], rf[:, :, None],
-                         (R - 1) - rf[:, :, None]) / (R - 1)
+                         (R - 1) - rf[:, :, None]) / (R - 1)   # (D,Rr,C)
         d_row = rf / (R - 1)
-        sub_off = jnp.take(batch.sub_offsets, s, axis=1)
-        lam_sp = jnp.zeros((D, P), jnp.float32)
-        var_tras = None
-        for p in range(len(PARAMS)):
-            var = (batch.k_bl[:, p][:, None, None, None] * d_bl[:, None, :, :]
-                   + batch.k_wl[:, p][:, None, None, None]
-                   * d_wl[None, None, None, :]
-                   + batch.k_mat[:, p][:, None, None, None]
-                   * d_mat[None, :, None, None]
-                   + batch.k_row[:, p][:, None, None, None]
-                   * d_row[:, None, :, None])
+        lam = var_tras = None
+        for p, (base, kbl, kwl, kmat, krow), t_cell in zip(pids, coefs,
+                                                          t_cells):
+            var = (kbl[:, None, None, None] * d_bl[:, None, :, :]
+                   + kwl[:, None, None, None] * d_wl[None, None, None, :]
+                   + kmat[:, None, None, None] * d_mat[None, :, None, None]
+                   + krow[:, None, None, None] * d_row[:, None, :, None])
             if p == 1:
                 var_tras = var  # tRAS (charge restore) drives retention too
-            t = batch.base[:, p][:, None, None, None, None] \
-                + stress[None, :, None, None, None] * var[:, None, :, :, :]
+            t = base[:, None, None, None, None] \
+                + stress[None, :, None, None, None] \
+                * var[:, None, :, :, :]                  # (D,P,M,Rr,C)
             t = t + adder[:, None, None, None, None]
             if extra is not None:
                 t = t + extra[:, None, None, None, None]
             t = t + chip0[:, None, None, None, None]
-            t = t + sub_off[:, None, None, None, None]
-            t_cell = t_subs[:, s, p][:, None, None, None, None]
-            pr = fail_mixture(t, t_cell, batch.sigma[:, None, None, None, None],
+            t = t + jnp.take(batch.sub_offsets, s,
+                             axis=1)[:, None, None, None, None]
+            pr = fail_mixture(t, t_cell,
+                              batch.sigma[:, None, None, None, None],
                               batch.outlier_rate[:, None, None, None, None],
                               batch.outlier_ns[:, None, None, None, None],
                               xp=jnp)
-            lam_sp = lam_sp + channel_lam(pr)
-        if retention:
+            lam = channel_lam(pr) if lam is None else lam + channel_lam(pr)
+        if ret_x is not None:
             slow = stress[None, :, None, None, None] \
                 * var_tras[:, None, :, :, :]
             pr = retention_fail_mixture(
@@ -495,16 +428,17 @@ def _op_region_eval(batch: DimmBatch, t_subs, rows, stress, adder, extra,
                 batch.ret_sigma[:, None, None, None, None],
                 batch.outlier_rate[:, None, None, None, None],
                 batch.ret_drop[:, None, None, None, None], xp=jnp)
-            lam_sp = lam_sp + channel_lam(pr)
-        u = query_uniform(batch.serial[:, None], lane, key_q, int(multibit),
+            lam = lam + channel_lam(pr)
+        u = query_uniform(batch.serial[:, None], lane, key_s, int(multibit),
                           s, pat_idx, xp=jnp)
-        fail_s = jnp.any(u < -jnp.expm1(-lam_sp), axis=1)    # (D,)
-        bank_oh = bank_ids == s // subs_per_bank
+        fail_s = jnp.any(u < -jnp.expm1(-lam), axis=1)   # (D,)
+        bank_oh = bank_ids == s // subs_per_bank         # (banks,)
         fails_acc = fails_acc | (fail_s[:, None] & bank_oh[None, :])
-        lam_acc = lam_acc + lam_sp.sum(axis=1)[:, None] \
+        lam_acc = lam_acc + lam.sum(axis=1)[:, None] \
             * bank_oh.astype(jnp.float32)[None, :]
         return (fails_acc, lam_acc), None
 
+    D = batch.serial.shape[0]
     init = (jnp.zeros((D, banks), bool), jnp.zeros((D, banks), jnp.float32))
     (fails, lam_total), _ = jax.lax.scan(per_subarray, init, jnp.arange(S))
     return fails, lam_total
@@ -569,9 +503,9 @@ def _sweep_axis(batch: DimmBatch, axis: str, t_subs, rows, stress,
 
     def step(_, xs):
         extra_g, adder_g, key_g, retx = xs
-        fail, _ = _op_region_eval(batch, t_subs, rows, stress, adder_g,
-                                  extra_g, lane, key_g, iters, multibit,
-                                  banks, retention, retx)
+        fail, _ = _region_eval(batch, _TIMING_IDX, t_subs, rows, stress,
+                               adder_g, iters, multibit, banks, extra_g,
+                               lane, key_g, retx if retention else None)
         return None, fail
 
     _, stops = jax.lax.scan(step, None,
@@ -1163,7 +1097,8 @@ def _op_grid_impl(batch: DimmBatch, rows, stress, t_g, adders_dg, shifts_dg,
                   keys_g, retx_g, *, iters: int, multibit: bool,
                   banks: int = 1, retention: bool = True):
     """lax.scan over a static operating-point grid: per point, the full
-    two-channel region evaluation of every DIMM (``_op_region_eval``).
+    two-channel region evaluation of every DIMM (``_region_eval`` over all
+    four timing parameters).
     Returns ``(fails, lam)`` shaped (D, G, banks) — per-DIMM results are
     independent across points (no sweep/stop logic), so the scan carries
     no state and chunk/shard partitions of D commute with it.
@@ -1174,9 +1109,10 @@ def _op_grid_impl(batch: DimmBatch, rows, stress, t_g, adders_dg, shifts_dg,
     def point(_, xs):
         t_pt, adder_g, shift_g, key_g, retx = xs
         t_subs = jnp.broadcast_to(t_pt[None, None, :], (D, S, len(PARAMS)))
-        return None, _op_region_eval(batch, t_subs, rows, stress, adder_g,
-                                     shift_g, OP_GRID_LANE, key_g, iters,
-                                     multibit, banks, retention, retx)
+        return None, _region_eval(batch, _TIMING_IDX, t_subs, rows, stress,
+                                  adder_g, iters, multibit, banks, shift_g,
+                                  OP_GRID_LANE, key_g,
+                                  retx if retention else None)
 
     xs = (t_g, adders_dg.T, shifts_dg.T, keys_g, retx_g)
     _, (fails, lam) = jax.lax.scan(point, None, xs)      # (G, D, banks)
@@ -1226,32 +1162,25 @@ def operating_grid_arrays(batch: DimmBatch, points, *,
 # --------------------------------------------------- full-grid batched API
 
 def _pack_coeffs(batch: DimmBatch, pidx: int, t_op, stress, adder,
-                 chip, sub_idx):
+                 chip, sub_idx, shift=None, ret_x=None):
     """(D, 9) folded per-DIMM coefficient rows for the fail_prob kernel;
-    ``adder`` is the host-computed (D,) operating-condition term."""
+    ``adder`` is the host-computed (D,) operating-condition term.  With a
+    (D,) voltage ``shift``, the (D, 15) operating-point rows: the supply
+    shift and the retention channel (ret_base, ret_k, the scalar retention
+    stress ``ret_x``, ret_sigma, ret_drop) follow the 9."""
     base_eff = (batch.base[:, pidx] + adder + batch.chip_offsets[:, chip]
                 + jnp.take(batch.sub_offsets, sub_idx, axis=1))
-    return jnp.stack([
+    cols = [
         base_eff, stress * batch.k_bl[:, pidx], stress * batch.k_wl[:, pidx],
         stress * batch.k_mat[:, pidx], stress * batch.k_row[:, pidx],
         jnp.full_like(base_eff, t_op), batch.sigma, batch.outlier_rate,
         batch.outlier_ns,
-    ], axis=1).astype(jnp.float32)
-
-
-def _pack_op_coeffs(batch: DimmBatch, pidx: int, t_op, stress, adder,
-                    chip, sub_idx, shift, ret_x):
-    """(D, 15) operating-point coefficient rows for the fail_prob_op kernel:
-    the 9 access coefficients of ``_pack_coeffs`` plus the host-computed
-    (D,) voltage shift and the retention channel (ret_base, ret_k, the
-    scalar retention stress ``ret_x``, ret_sigma, ret_drop)."""
-    cf = _pack_coeffs(batch, pidx, t_op, stress, adder, chip, sub_idx)
-    extra = jnp.stack([
-        jnp.asarray(shift, jnp.float32), batch.ret_base, batch.ret_k,
-        jnp.full_like(batch.ret_base, np.float32(ret_x)), batch.ret_sigma,
-        batch.ret_drop,
-    ], axis=1).astype(jnp.float32)
-    return jnp.concatenate([cf, extra], axis=1)
+    ]
+    if shift is not None:
+        cols += [jnp.asarray(shift, jnp.float32), batch.ret_base, batch.ret_k,
+                 jnp.full_like(batch.ret_base, np.float32(ret_x)),
+                 batch.ret_sigma, batch.ret_drop]
+    return jnp.stack(cols, axis=1).astype(jnp.float32)
 
 
 def _fail_prob_impl(row_src, d_mat, coeffs, *, cols: int, pallas: bool):

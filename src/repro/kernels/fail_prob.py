@@ -5,7 +5,9 @@ model in VMEM: distance-derived t_req (bitline / wordline / mat-position /
 row-index terms, Figs 3-4/9), the operating-condition and chip/subarray
 offsets (folded into the coefficient row), the heavy-tail weak-cell mixture
 (Sec 6.1/App C), and the post-manufacturing row repair (resolved upstream
-into the ``row_src`` index table).  HBM traffic is one read of the row-source
+into the ``row_src`` index table).  At a full operating point two static
+flags add the supply-voltage shift and the retention error channel, read
+from a wider coefficient row.  HBM traffic is one read of the row-source
 and coefficient rows and one write of the (mats, rows, cols) grid.
 
 Callers that only sum the grid over mats and columns (the campaign's
@@ -40,34 +42,19 @@ _LANES = 128
 
 
 def cell_probs(rf, colf, even, d_mat, cf, n_rows: int, n_cols: int,
-               open_bitline: bool = True):
+               open_bitline: bool = True, voltage: bool = False,
+               retention: bool = False):
     """Failure probability of each cell; shared by the kernel and the oracle.
 
     ``rf``/``colf``/``even`` broadcast to the (rows, cols) slab; ``cf`` is the
-    folded 9-coefficient row (stress pre-multiplied into the k's, all
-    additive offsets folded into cf[0]).
-    """
-    if open_bitline:
-        d_bl = jnp.where(even, rf, (n_rows - 1.0) - rf) / (n_rows - 1.0)
-    else:
-        d_bl = rf / (n_rows - 1.0)
-    d_wl = colf / (n_cols - 1.0)
-    d_row = rf / (n_rows - 1.0)
-    t = cf[0] + cf[1] * d_bl + cf[2] * d_wl + cf[3] * d_mat + cf[4] * d_row
-    return fail_mixture(t, cf[5], cf[6], cf[7], cf[8], xp=jnp)
-
-
-def op_cell_probs(rf, colf, even, d_mat, cf, n_rows: int, n_cols: int,
-                  open_bitline: bool = True, voltage: bool = False,
-                  retention: bool = False):
-    """Per-cell failure probability at a full *operating point*: the access
-    channel of ``cell_probs`` shifted by the folded voltage term (cf[9],
-    static ``voltage``) plus — static ``retention`` — the refresh/temperature
-    retention channel, whose slowness is the same stress-premultiplied
-    design-variation sum the access channel uses (``t - cf[0]``).  Channel
-    probabilities ADD (expected-count channels), so summing the returned grid
-    over cells yields the two-channel lambda directly.  With both flags off
-    this is graph-identical to ``cell_probs`` on cf[:9].
+    folded coefficient row (stress pre-multiplied into the k's, all additive
+    offsets folded into cf[0]).  Static ``voltage`` shifts the access channel
+    by the folded supply term cf[9]; static ``retention`` adds the
+    refresh/temperature retention channel, whose slowness is the same
+    stress-premultiplied design-variation sum the access channel uses
+    (``t - cf[0]``).  Channel probabilities ADD (expected-count channels), so
+    summing the returned grid over cells yields the two-channel lambda.  With
+    both flags off only cf[:N_COEFFS] is read.
     """
     if open_bitline:
         d_bl = jnp.where(even, rf, (n_rows - 1.0) - rf) / (n_rows - 1.0)
@@ -86,10 +73,15 @@ def op_cell_probs(rf, colf, even, d_mat, cf, n_rows: int, n_cols: int,
     return p
 
 
-def _grid_call(probs, row_src, d_mat, coeffs, n_coeffs: int, cols: int,
-               row_tile: int | None, interpret: bool):
-    """The shared (M, R, C) grid program of both kernels; ``probs(rf, colf,
-    even, d_mat, cf, n_rows)`` is the per-cell formula.
+def _n_coeffs(voltage: bool, retention: bool) -> int:
+    """Width of the coefficient row the kernel reads under these flags."""
+    return N_OP_COEFFS if voltage or retention else N_COEFFS
+
+
+def _grid_call(row_src, d_mat, coeffs, cols: int, open_bitline: bool,
+               voltage: bool, retention: bool, row_tile: int | None,
+               interpret: bool):
+    """The (M, R, C) grid of ``cell_probs``.
 
     One program owns one (mat, row-tile) slab.  The per-mat precharge delay
     and the folded coefficient row are read as scalars from SMEM (Mosaic
@@ -103,16 +95,18 @@ def _grid_call(probs, row_src, d_mat, coeffs, n_coeffs: int, cols: int,
     # dim, and Mosaic accepts the (1, n) trailing block only as the whole
     # array's trailing dims
     d_mat = jnp.asarray(d_mat, jnp.float32).reshape(1, -1)
-    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(1, n_coeffs)
+    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(1, -1)
+    n = _n_coeffs(voltage, retention)
     row_src, R, tile = _row_grid(row_src, row_tile)
     Rp, M = row_src.shape[0], d_mat.shape[1]
 
     def kernel(rs_ref, dm_ref, cf_ref, out_ref):
         rf = jnp.broadcast_to(rs_ref[...].astype(jnp.float32), (tile, cols))
         col = jax.lax.broadcasted_iota(jnp.int32, (tile, cols), 1)
-        cf = [cf_ref[0, k] for k in range(n_coeffs)]
-        out_ref[...] = probs(rf, col.astype(jnp.float32), (col % 2) == 0,
-                             dm_ref[0, pl.program_id(0)], cf, R)[None]
+        cf = [cf_ref[0, k] for k in range(n)]
+        out_ref[...] = cell_probs(rf, col.astype(jnp.float32), (col % 2) == 0,
+                                  dm_ref[0, pl.program_id(0)], cf, R, cols,
+                                  open_bitline, voltage, retention)[None]
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
@@ -139,7 +133,8 @@ def _row_grid(row_src, row_tile: int | None):
 
 
 def _row_sum_call(row_src, d_mat, coeffs, cols: int, open_bitline: bool,
-                  row_tile: int | None, interpret: bool):
+                  voltage: bool, retention: bool, row_tile: int | None,
+                  interpret: bool):
     """The (R,) sum over mats and columns of ``cell_probs``, with no grid.
 
     One program owns one row tile and walks it in blocks of ``_ROW_BLOCK``
@@ -154,7 +149,8 @@ def _row_sum_call(row_src, d_mat, coeffs, cols: int, open_bitline: bool,
     traffic is the row-source read and R floats written."""
     row_src = jnp.asarray(row_src, jnp.int32).reshape(-1, 1)
     d_mat = jnp.asarray(d_mat, jnp.float32).reshape(1, -1)
-    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(1, N_COEFFS)
+    coeffs = jnp.asarray(coeffs, jnp.float32).reshape(1, -1)
+    n = _n_coeffs(voltage, retention)
     R = row_src.shape[0]
     tile = -(-(row_tile or R) // _ROW_BLOCK) * _ROW_BLOCK
     row_src, R, tile = _row_grid(row_src, tile)
@@ -163,7 +159,7 @@ def _row_sum_call(row_src, d_mat, coeffs, cols: int, open_bitline: bool,
     f32 = jnp.float32
 
     def kernel(rs_ref, dm_ref, cf_ref, out_ref):
-        cf = [cf_ref[0, k] for k in range(N_COEFFS)]
+        cf = [cf_ref[0, k] for k in range(n)]
         lane = jax.lax.broadcasted_iota(jnp.int32, (_ROW_BLOCK, strip), 1)
 
         def row_block(b, carry):
@@ -178,7 +174,8 @@ def _row_sum_call(row_src, d_mat, coeffs, cols: int, open_bitline: bool,
 
                 def mat(m, acc):
                     return acc + cell_probs(rf, colf, even, dm_ref[0, m], cf,
-                                            R, cols, open_bitline)
+                                            R, cols, open_bitline, voltage,
+                                            retention)
 
                 return jax.lax.fori_loop(0, M, mat, acc, unroll=True)
 
@@ -202,46 +199,25 @@ def _row_sum_call(row_src, d_mat, coeffs, cols: int, open_bitline: bool,
 
 
 @functools.partial(jax.jit, static_argnames=("cols", "open_bitline",
+                                             "voltage", "retention",
                                              "row_tile", "reduce_rows",
                                              "interpret"))
 def fail_prob(row_src, d_mat, coeffs, *, cols: int, interpret: bool,
-              open_bitline: bool = True, row_tile: int | None = None,
+              open_bitline: bool = True, voltage: bool = False,
+              retention: bool = False, row_tile: int | None = None,
               reduce_rows: bool = False):
     """row_src: (R,) int32 repair-resolved internal rows; d_mat: (M,) f32
-    precharge-arrival delays; coeffs: (N_COEFFS,) f32 folded coefficient row.
-    Returns the (M, R, C) failure-probability grid, or with ``reduce_rows``
-    its (R,) sum over mats and columns, computed without writing the grid.
+    precharge-arrival delays; coeffs: the f32 folded coefficient row, whose
+    first N_COEFFS entries are the access channel's and, where ``voltage``
+    or ``retention`` is set, N_OP_COEFFS wide: ``[*access 0-8, vdd_shift,
+    ret_base, ret_k, ret_x, ret_sigma, ret_drop]``.  Returns the (M, R, C)
+    failure-probability grid, or with ``reduce_rows`` its (R,) sum over mats
+    and columns, computed without writing the grid.
 
     ``row_tile`` splits the row axis into a second grid dimension (masked
     tail via pad-to-tile + slice-back); per-cell results are bit-identical at
     any tile because each row's computation is independent.  In the row-sum
     mode it is the rows one program owns, rounded up to whole row blocks."""
-    if reduce_rows:
-        return _row_sum_call(row_src, d_mat, coeffs, cols, open_bitline,
-                             row_tile, interpret)
-
-    def probs(rf, colf, even, dm, cf, n_rows):
-        return cell_probs(rf, colf, even, dm, cf, n_rows, cols, open_bitline)
-
-    return _grid_call(probs, row_src, d_mat, coeffs, N_COEFFS, cols,
-                      row_tile, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("cols", "open_bitline",
-                                             "voltage", "retention",
-                                             "row_tile", "interpret"))
-def fail_prob_op(row_src, d_mat, coeffs, *, cols: int, interpret: bool,
-                 open_bitline: bool = True, voltage: bool = False,
-                 retention: bool = False, row_tile: int | None = None):
-    """Operating-point variant of ``fail_prob``: coeffs is the
-    (N_OP_COEFFS,) f32 row ``[*access 0-8, vdd_shift, ret_base, ret_k,
-    ret_x, ret_sigma, ret_drop]``; static ``voltage``/``retention`` gate the
-    extra terms (both off => value-identical to ``fail_prob`` on cf[:9]).
-    Returns the (M, R, C) summed two-channel probability grid.  ``row_tile``
-    tiles the row axis exactly as in ``fail_prob``."""
-    def probs(rf, colf, even, dm, cf, n_rows):
-        return op_cell_probs(rf, colf, even, dm, cf, n_rows, cols,
-                             open_bitline, voltage, retention)
-
-    return _grid_call(probs, row_src, d_mat, coeffs, N_OP_COEFFS, cols,
-                      row_tile, interpret)
+    call = _row_sum_call if reduce_rows else _grid_call
+    return call(row_src, d_mat, coeffs, cols, open_bitline, voltage,
+                retention, row_tile, interpret)

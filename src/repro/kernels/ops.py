@@ -22,7 +22,7 @@ special-cased only TPU — a GPU host silently ran every kernel interpreted.
 ``use_pallas()`` / ``interpret_mode()`` survive as *derived* views for the
 tile heuristics in substrate/discovery.
 
-The nine public wrappers are generated from ``kernels/registry.py`` by one
+The eight public wrappers are generated from ``kernels/registry.py`` by one
 dispatcher: route to the oracle, or to the Pallas impl with tile kwargs from
 the measured autotuner (``kernels/tune.py``).  Public signatures are
 unchanged; the ``pallas=None`` convention still resolves the backend at
@@ -160,7 +160,7 @@ def _resolve(spec, pallas: bool | None) -> tuple[str, str]:
 
 def _dispatch(name: str, args: tuple, kw: dict, pallas: bool | None,
               tiles: dict | None = None):
-    """The one route for all nine sites: oracle, or Pallas with tile kwargs
+    """The one route for all eight sites: oracle, or Pallas with tile kwargs
     from the explicit override / the autotune cache / the kernel defaults."""
     spec = REGISTRY[name]
     route, tag = _resolve(spec, pallas)
@@ -190,57 +190,32 @@ def secded_syndrome(code_bits, tile: int | None = None, *,
 
 
 def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True,
+              voltage: bool = False, retention: bool = False,
               reduce_rows: bool = False, row_tile: int | None = None,
               pallas: bool | None = None):
     """The (M, R, C) grid, or with ``reduce_rows`` its (R,) row sums.
-    ``pallas=None`` resolves the backend tag at trace time; callers that
-    cache compiled programs pass the resolved bool so the backend choice keys
-    their cache (the ``substrate._shuffling_jit`` convention)."""
+    ``voltage``/``retention`` add the operating point's supply shift and
+    retention channel, read from the N_OP_COEFFS-wide row.  ``pallas=None``
+    resolves the backend tag at trace time; callers that cache compiled
+    programs pass the resolved bool so the backend choice keys their cache
+    (the ``substrate._shuffling_jit`` convention)."""
     return _dispatch("fail_prob", (row_src, d_mat, coeffs),
                      dict(cols=cols, open_bitline=open_bitline,
+                          voltage=voltage, retention=retention,
                           reduce_rows=reduce_rows), pallas,
                      None if row_tile is None else {"row_tile": row_tile})
 
 
-def fail_prob_batch(row_src, d_mat, coeffs, *, cols: int,
-                    open_bitline: bool = True, reduce_rows: bool = False,
-                    pallas: bool | None = None):
-    """``fail_prob`` vmapped over a leading population (DIMM) axis of
-    ``row_src``/``coeffs`` — the dispatch the batched substrate and its
-    sharded routes share (one dispatch site: the per-DIMM ``fail_prob``)."""
+def fail_prob_batch(row_src, d_mat, coeffs, *, pallas: bool | None = None,
+                    **kw):
+    """``fail_prob`` (same keyword arguments) vmapped over a leading
+    population (DIMM) axis of ``row_src``/``coeffs`` — the dispatch the
+    batched substrate and its sharded routes share (one dispatch site: the
+    per-DIMM ``fail_prob``)."""
     if pallas is None:
         pallas = use_pallas()
-    fn = functools.partial(fail_prob, cols=cols, open_bitline=open_bitline,
-                           reduce_rows=reduce_rows, pallas=pallas)
+    fn = functools.partial(fail_prob, pallas=pallas, **kw)
     return jax.vmap(fn, in_axes=REGISTRY["fail_prob"].batch_in_axes)(
-        row_src, d_mat, coeffs)
-
-
-def fail_prob_op(row_src, d_mat, coeffs, *, cols: int,
-                 open_bitline: bool = True, voltage: bool = False,
-                 retention: bool = False, row_tile: int | None = None,
-                 pallas: bool | None = None):
-    """Operating-point (two error channel) variant of ``fail_prob``: coeffs
-    is the (N_OP_COEFFS,) row with the folded voltage shift and retention
-    channel appended; static ``voltage``/``retention`` flags gate them (both
-    off => value-identical to ``fail_prob`` on coeffs[:9]).  ``pallas=None``
-    resolves the backend at trace time, per the ``fail_prob`` convention."""
-    return _dispatch("fail_prob_op", (row_src, d_mat, coeffs),
-                     dict(cols=cols, open_bitline=open_bitline,
-                          voltage=voltage, retention=retention), pallas,
-                     None if row_tile is None else {"row_tile": row_tile})
-
-
-def fail_prob_op_batch(row_src, d_mat, coeffs, *, cols: int,
-                       open_bitline: bool = True, voltage: bool = False,
-                       retention: bool = False, pallas: bool | None = None):
-    """``fail_prob_op`` vmapped over a leading population (DIMM) axis of
-    ``row_src``/``coeffs``, mirroring ``fail_prob_batch``."""
-    if pallas is None:
-        pallas = use_pallas()
-    fn = functools.partial(fail_prob_op, cols=cols, open_bitline=open_bitline,
-                           voltage=voltage, retention=retention, pallas=pallas)
-    return jax.vmap(fn, in_axes=REGISTRY["fail_prob_op"].batch_in_axes)(
         row_src, d_mat, coeffs)
 
 
@@ -289,4 +264,4 @@ def wkv6(r, k, v, wlog, u, *, tile_bh: int | None = None,
 
 __all__ = ["backend_tag", "force_backend", "use_pallas", "interpret_mode",
            "valid_tags", "KERNEL_NAMES", *KERNEL_NAMES,
-           "fail_prob_batch", "fail_prob_op_batch"]
+           "fail_prob_batch"]

@@ -12,10 +12,12 @@ from repro.models.rwkv6 import wkv6_scan as _wkv6_scan
 
 
 def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True,
+              voltage: bool = False, retention: bool = False,
               reduce_rows: bool = False):
     """(M, R, C) failure-probability grid — pure-jnp oracle of the Pallas
-    kernel in kernels/fail_prob.py (same formula helper, same bits).  With
-    ``reduce_rows``, the grid's (R,) sum over mats and columns."""
+    kernel in kernels/fail_prob.py (same formula helper, same bits), with
+    the same ``voltage``/``retention`` channel flags.  With ``reduce_rows``,
+    the grid's (R,) sum over mats and columns."""
     from repro.kernels.fail_prob import cell_probs
     row_src = jnp.asarray(row_src, jnp.int32)
     d_mat = jnp.asarray(d_mat, jnp.float32)
@@ -27,29 +29,8 @@ def fail_prob(row_src, d_mat, coeffs, *, cols: int, open_bitline: bool = True,
     even = (jax.lax.broadcasted_iota(jnp.int32, (d_mat.shape[0], R, cols), 2)
             % 2) == 0
     grid = cell_probs(rf, colf, even, d_mat[:, None, None], coeffs, R, cols,
-                      open_bitline)
+                      open_bitline, voltage, retention)
     return grid.sum(axis=(0, 2)) if reduce_rows else grid
-
-
-def fail_prob_op(row_src, d_mat, coeffs, *, cols: int,
-                 open_bitline: bool = True, voltage: bool = False,
-                 retention: bool = False):
-    """(M, R, C) two-channel (access + retention) probability grid at one
-    operating point — pure-jnp oracle of ``kernels/fail_prob.py::
-    fail_prob_op`` (same ``op_cell_probs`` helper, same bits; both flags off
-    reduces to the ``fail_prob`` graph on coeffs[:9])."""
-    from repro.kernels.fail_prob import op_cell_probs
-    row_src = jnp.asarray(row_src, jnp.int32)
-    d_mat = jnp.asarray(d_mat, jnp.float32)
-    coeffs = jnp.asarray(coeffs, jnp.float32)
-    R = row_src.shape[0]
-    rf = jnp.broadcast_to(row_src.astype(jnp.float32)[None, :, None],
-                          (d_mat.shape[0], R, cols))
-    colf = jax.lax.broadcasted_iota(jnp.float32, (d_mat.shape[0], R, cols), 2)
-    even = (jax.lax.broadcasted_iota(jnp.int32, (d_mat.shape[0], R, cols), 2)
-            % 2) == 0
-    return op_cell_probs(rf, colf, even, d_mat[:, None, None], coeffs, R,
-                         cols, open_bitline, voltage, retention)
 
 
 def bank_sched(q_bank, q_row, q_write, q_arrive, q_valid,

@@ -1,10 +1,10 @@
-"""Declarative kernel registry: the nine Pallas dispatch sites as data.
+"""Declarative kernel registry: the eight Pallas dispatch sites as data.
 
 Each :class:`KernelSpec` names one dispatch site (the public wrapper in
 ``kernels/ops.py``), its Pallas implementation, its pure-jnp oracle
 (``kernels/ref.py``), the tile space the autotuner (``kernels/tune.py``)
 may sweep, and the backends it has a *compiled* lowering for.  ``ops.py``
-used to hand-write the nine wrappers; now one generic dispatcher walks this
+used to hand-write the wrappers; now one generic dispatcher walks this
 table, so bench (``benchmarks/kernel_bench.py``), tests, and the tuner can
 enumerate every kernel without keeping a parallel list in sync.
 
@@ -31,7 +31,6 @@ from repro.kernels import ref as _ref
 from repro.kernels.bank_sched import bank_sched as _sched_pallas
 from repro.kernels.bit_signature import bit_signature as _bs_pallas
 from repro.kernels.fail_prob import fail_prob as _fp_pallas
-from repro.kernels.fail_prob import fail_prob_op as _fpo_pallas
 from repro.kernels.rc_transient import rc_transient as _rc_pallas
 from repro.kernels.secded import encode_checks as _enc_pallas
 from repro.kernels.secded import syndrome as _syn_pallas
@@ -97,11 +96,6 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
                     {"row_tile": 256}),
         bucket=_fail_prob_bucket, batch_in_axes=(0, None, 0)),
     KernelSpec(
-        "fail_prob_op", _fpo_pallas,
-        tile_space=({}, {"row_tile": 64}, {"row_tile": 128},
-                    {"row_tile": 256}),
-        bucket=_fail_prob_bucket, batch_in_axes=(0, None, 0)),
-    KernelSpec(
         "bit_signature", _bs_pallas,
         tile_space=({}, {"tile": 64}, {"tile": 128}, {"tile": 512})),
     KernelSpec(
@@ -122,5 +116,5 @@ REGISTRY: dict[str, KernelSpec] = {s.name: s for s in (
         bucket=_wkv6_bucket, compiled=(TPU,)),
 )}
 
-#: the nine dispatch-site names, in registry order (bench/tests iterate this)
+#: the eight dispatch-site names, in registry order (bench/tests iterate this)
 KERNEL_NAMES: tuple[str, ...] = tuple(REGISTRY)

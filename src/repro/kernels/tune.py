@@ -1,11 +1,15 @@
 """Measured tile autotuner for the kernel registry.
 
-One winner per ``(kernel, backend_tag, shape-bucket)``: the first *concrete*
-call on a sweep-eligible route times every candidate in the spec's
-``tile_space`` (compile excluded, best-of-``_TIMING_ITERS``) and caches the
-fastest setting — an in-process dict, following flashinfer's cached-workspace
-idiom, with optional JSON persistence under ``benchmarks/`` so a tuned
-trajectory can be replayed without re-measuring.
+One winner per ``(kernel, backend_tag, shape-bucket, mode)``, where the mode
+names the output and channel flags the call sets (``reduce_rows``,
+``voltage``, ``retention``): a row-sum call or a two-channel call does other
+work per tile than a grid call of the same bucket, so none inherits another's
+winner.  The first *concrete* call on a sweep-eligible route times every
+candidate in the spec's ``tile_space`` (compile excluded,
+best-of-``_TIMING_ITERS``) and caches the fastest setting — an in-process
+dict, following flashinfer's cached-workspace idiom, with optional JSON
+persistence under ``benchmarks/`` so a tuned trajectory can be replayed
+without re-measuring.
 
 Hard rules, in order:
 
@@ -40,8 +44,11 @@ from repro.obs import REGISTRY as _OBS_REGISTRY
 
 _TIMING_ITERS = 3
 
-#: (kernel, backend_tag, bucket) -> winning tile kwargs
-_TUNE_CACHE: dict[tuple[str, str, int], dict[str, Any]] = {}
+#: the call flags that select another kernel body: part of the cache key
+MODE_FLAGS = ("reduce_rows", "voltage", "retention")
+
+#: (kernel, backend_tag, bucket, mode) -> winning tile kwargs
+_TUNE_CACHE: dict[tuple[str, str, int, str], dict[str, Any]] = {}
 
 _TUNE_SWEEPS = _OBS_REGISTRY.counter(
     "repro_kernel_tune_total",
@@ -68,8 +75,15 @@ def _has_tracers(args) -> bool:
     return any(isinstance(a, jax.core.Tracer) for a in jax.tree.leaves(args))
 
 
-def lookup(kernel: str, backend: str, bucket: int) -> dict[str, Any] | None:
-    return _TUNE_CACHE.get((kernel, backend, bucket))
+def call_mode(kw) -> str:
+    """The cache key's mode: the ``MODE_FLAGS`` a call sets, joined by
+    ``+`` (``""`` for none)."""
+    return "+".join(f for f in MODE_FLAGS if kw.get(f))
+
+
+def lookup(kernel: str, backend: str, bucket: int,
+           mode: str = "") -> dict[str, Any] | None:
+    return _TUNE_CACHE.get((kernel, backend, bucket, mode))
 
 
 def clear() -> None:
@@ -104,11 +118,12 @@ def get_tiles(spec, backend_tag: str, route: str, args, kw) -> dict[str, Any]:
 
     ``route`` is the ops-layer route ("interpret" / "compiled"); ``args``/
     ``kw`` are the call's arrays and statics.  Returns the cached winner for
-    this (kernel, backend, bucket), sweeping first when eligible; defaults
-    (``tile_space[0]``, i.e. the kernels' built-in constants) otherwise.
+    this (kernel, backend, bucket, mode), sweeping first when eligible;
+    defaults (``tile_space[0]``, i.e. the kernels' built-in constants)
+    otherwise.
     """
     bucket = bucket_pow2(spec.bucket(args, kw))
-    key = (spec.name, backend_tag, bucket)
+    key = (spec.name, backend_tag, bucket, call_mode(kw))
     hit = _TUNE_CACHE.get(key)
     if hit is not None:
         return dict(hit)
@@ -129,10 +144,11 @@ def get_tiles(spec, backend_tag: str, route: str, args, kw) -> dict[str, Any]:
 # --------------------------------------------------------- JSON persistence
 
 def save_cache(path: str | Path = DEFAULT_CACHE_PATH) -> Path:
-    """Persist the in-process winners; key format ``kernel|backend|bucket``."""
+    """Persist the in-process winners; key format
+    ``kernel|backend|bucket|mode``."""
     path = Path(path)
-    blob = {f"{k}|{b}|{n}": tiles
-            for (k, b, n), tiles in sorted(_TUNE_CACHE.items())}
+    blob = {"|".join(map(str, key)): tiles
+            for key, tiles in sorted(_TUNE_CACHE.items())}
     path.write_text(json.dumps(blob, indent=2) + "\n")
     return path
 
@@ -146,6 +162,6 @@ def load_cache(path: str | Path = DEFAULT_CACHE_PATH) -> int:
         return 0
     blob = json.loads(path.read_text())
     for key, tiles in blob.items():
-        kernel, backend, bucket = key.rsplit("|", 2)
-        _TUNE_CACHE[(kernel, backend, int(bucket))] = dict(tiles)
+        kernel, backend, bucket, mode = key.split("|")
+        _TUNE_CACHE[(kernel, backend, int(bucket), mode)] = dict(tiles)
     return len(blob)

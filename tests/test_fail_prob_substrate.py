@@ -35,23 +35,58 @@ def test_query_uniform_numpy_jax_bit_identical():
 
 # ------------------------------------------------------------ kernel parity
 
-def test_fail_prob_kernel_matches_ref():
+def _op_coeffs():
+    cf9 = np.array([3.9, 2.1, 0.4, 0.8, 0.4, 7.5, 0.15, 3e-6, 3.5],
+                   np.float32)
+    extra = np.array([1.2, 4.0, 0.4, 1.0, 0.3, 1.2], np.float32)
+    return cf9, np.concatenate([cf9, extra])
+
+
+@pytest.mark.parametrize("voltage,retention",
+                         [(False, False), (True, False), (False, True),
+                          (True, True)])
+def test_fail_prob_kernel_matches_ref(voltage, retention):
     """Pallas (interpret) and the pure-jnp oracle share the formula helper;
     XLA fuses the two programs differently (FMA contraction), so agreement
-    is to 1 float32 ulp, not literal bit equality."""
+    is to 1 float32 ulp, not literal bit equality.  With a channel flag on
+    the row is 15 wide and the extra channels are live."""
     from repro.kernels import ref
     from repro.kernels.fail_prob import fail_prob as fp_pallas
-    rng = np.random.default_rng(3)
+    rng = np.random.default_rng(3 if not (voltage or retention) else 6)
     row_src = rng.integers(0, 64, 64).astype(np.int32)
     d_mat = np.linspace(0.1, 1.0, 4).astype(np.float32)
-    coeffs = np.array([3.9, 2.1, 0.4, 0.8, 0.4, 7.5, 0.15, 3e-6, 3.5],
-                      np.float32)
-    k = np.asarray(fp_pallas(row_src, d_mat, coeffs, cols=64, interpret=True))
-    r = np.asarray(ref.fail_prob(row_src, d_mat, coeffs, cols=64))
+    cf9, cf15 = _op_coeffs()
+    coeffs = cf15 if voltage or retention else cf9
+    flags = dict(voltage=voltage, retention=retention)
+    k = np.asarray(fp_pallas(row_src, d_mat, coeffs, cols=64, interpret=True,
+                             **flags))
+    r = np.asarray(ref.fail_prob(row_src, d_mat, coeffs, cols=64, **flags))
     assert k.shape == (4, 64, 64)
-    np.testing.assert_allclose(k, r, atol=1e-6, rtol=0)
-    # probabilities stay in range on both paths
-    assert (k >= 0).all() and (k <= 1).all()
+    if voltage or retention:
+        np.testing.assert_allclose(k, r, atol=1e-5, rtol=1e-5)
+        # two summed per-cell channel probabilities: in [0, 2] on both paths
+        assert (k >= 0).all() and (k <= 2).all()
+    else:
+        np.testing.assert_allclose(k, r, atol=1e-6, rtol=0)
+        # probabilities stay in range on both paths
+        assert (k >= 0).all() and (k <= 1).all()
+
+
+def test_fail_prob_flags_off_15_wide_row_identical_to_9_wide():
+    """With both channel flags off the kernel reads the row's first 9
+    entries and traces the exact access-channel graph: a 15-wide row gives
+    the 9-wide row's grid, bit for bit, on both routes."""
+    from repro.kernels import ops
+    rng = np.random.default_rng(5)
+    row_src = rng.integers(0, 64, 64).astype(np.int32)
+    d_mat = np.linspace(0.1, 1.0, 4).astype(np.float32)
+    cf9, cf15 = _op_coeffs()
+    for pallas in (True, False):
+        np.testing.assert_array_equal(
+            np.asarray(ops.fail_prob(row_src, d_mat, cf9, cols=64,
+                                     pallas=pallas)),
+            np.asarray(ops.fail_prob(row_src, d_mat, cf15, cols=64,
+                                     pallas=pallas)))
 
 
 @pytest.mark.parametrize("param,t_op,pattern,subarray,chip",
@@ -72,73 +107,21 @@ def test_fail_prob_kernel_matches_numpy_grid(param, t_op, pattern, subarray,
         np.testing.assert_allclose(g[i], ref, atol=1e-5, rtol=1e-4)
 
 
-def test_fail_prob_dispatch_ref_mode(monkeypatch):
+@pytest.mark.parametrize("voltage,retention",
+                         [(False, False), (True, True)])
+def test_fail_prob_dispatch_ref_mode(monkeypatch, voltage, retention):
     monkeypatch.setenv("REPRO_FORCE_REF", "1")
     from repro.kernels import ops, ref
     row_src = np.arange(32, dtype=np.int32)
     d_mat = np.linspace(0.2, 1.0, 2).astype(np.float32)
     coeffs = np.array([4.0, 2.0, 0.5, 1.0, 0.3, 8.0, 0.2, 1e-5, 3.0],
                       np.float32)
+    if voltage or retention:
+        coeffs = _op_coeffs()[1]
+    flags = dict(voltage=voltage, retention=retention)
     np.testing.assert_array_equal(
-        np.asarray(ops.fail_prob(row_src, d_mat, coeffs, cols=32)),
-        np.asarray(ref.fail_prob(row_src, d_mat, coeffs, cols=32)))
-
-
-def _op_coeffs():
-    cf9 = np.array([3.9, 2.1, 0.4, 0.8, 0.4, 7.5, 0.15, 3e-6, 3.5],
-                   np.float32)
-    extra = np.array([1.2, 4.0, 0.4, 1.0, 0.3, 1.2], np.float32)
-    return cf9, np.concatenate([cf9, extra])
-
-
-def test_fail_prob_op_flags_off_identical_to_fail_prob():
-    """The operating-point kernel with both channel flags off traces the
-    exact cell_probs graph — value-identical to fail_prob, bit for bit."""
-    from repro.kernels import ops
-    rng = np.random.default_rng(5)
-    row_src = rng.integers(0, 64, 64).astype(np.int32)
-    d_mat = np.linspace(0.1, 1.0, 4).astype(np.float32)
-    cf9, cf15 = _op_coeffs()
-    for pallas in (True, False):
-        np.testing.assert_array_equal(
-            np.asarray(ops.fail_prob(row_src, d_mat, cf9, cols=64,
-                                     pallas=pallas)),
-            np.asarray(ops.fail_prob_op(row_src, d_mat, cf15, cols=64,
-                                        pallas=pallas)))
-
-
-@pytest.mark.parametrize("voltage,retention",
-                         [(True, False), (False, True), (True, True)])
-def test_fail_prob_op_kernel_matches_ref(voltage, retention):
-    """Pallas (interpret) vs jnp oracle with the extra channels live — the
-    same 1-float32-ulp contract as the base kernel (FMA contraction)."""
-    from repro.kernels import ref
-    from repro.kernels.fail_prob import fail_prob_op as fpo_pallas
-    rng = np.random.default_rng(6)
-    row_src = rng.integers(0, 64, 64).astype(np.int32)
-    d_mat = np.linspace(0.1, 1.0, 4).astype(np.float32)
-    _, cf15 = _op_coeffs()
-    k = np.asarray(fpo_pallas(row_src, d_mat, cf15, cols=64, voltage=voltage,
-                              retention=retention, interpret=True))
-    r = np.asarray(ref.fail_prob_op(row_src, d_mat, cf15, cols=64,
-                                    voltage=voltage, retention=retention))
-    assert k.shape == (4, 64, 64)
-    np.testing.assert_allclose(k, r, atol=1e-5, rtol=1e-5)
-    # two summed per-cell channel probabilities: in [0, 2] on both paths
-    assert (k >= 0).all() and (k <= 2).all()
-
-
-def test_fail_prob_op_dispatch_ref_mode(monkeypatch):
-    monkeypatch.setenv("REPRO_FORCE_REF", "1")
-    from repro.kernels import ops, ref
-    row_src = np.arange(32, dtype=np.int32)
-    d_mat = np.linspace(0.2, 1.0, 2).astype(np.float32)
-    _, cf15 = _op_coeffs()
-    np.testing.assert_array_equal(
-        np.asarray(ops.fail_prob_op(row_src, d_mat, cf15, cols=32,
-                                    voltage=True, retention=True)),
-        np.asarray(ref.fail_prob_op(row_src, d_mat, cf15, cols=32,
-                                    voltage=True, retention=True)))
+        np.asarray(ops.fail_prob(row_src, d_mat, coeffs, cols=32, **flags)),
+        np.asarray(ref.fail_prob(row_src, d_mat, coeffs, cols=32, **flags)))
 
 
 # --------------------------------------------------------- profiling parity

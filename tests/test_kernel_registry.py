@@ -4,9 +4,9 @@ The dispatch layer's promises: one ``backend_tag()`` authority with a fixed
 resolution order (force_backend > REPRO_FORCE_REF > REPRO_BACKEND > platform
 default), derived ``use_pallas()``/``interpret_mode()`` views that are
 correct on EVERY platform (the old heuristic special-cased TPU and silently
-interpreted on GPU), a registry that stays in lockstep with the nine public
+interpreted on GPU), a registry that stays in lockstep with the eight public
 dispatch sites, and an autotuner that sweeps at most once per (kernel,
-backend, bucket) and never under a trace.
+backend, bucket, mode) and never under a trace.
 """
 import json
 
@@ -98,11 +98,10 @@ def test_tpu_host_defaults_to_mosaic(monkeypatch):
 
 # ---------------------------------------------------------------- registry
 
-def test_registry_covers_the_nine_dispatch_sites():
+def test_registry_covers_the_eight_dispatch_sites():
     assert KERNEL_NAMES == (
-        "secded_encode", "secded_syndrome", "fail_prob", "fail_prob_op",
-        "bit_signature", "bank_sched", "diva_shuffle", "rc_transient",
-        "wkv6")
+        "secded_encode", "secded_syndrome", "fail_prob", "bit_signature",
+        "bank_sched", "diva_shuffle", "rc_transient", "wkv6")
     for name in KERNEL_NAMES:
         assert callable(getattr(ops, name)), name
 
@@ -223,6 +222,43 @@ def test_tune_cache_persistence_roundtrip(tmp_path, monkeypatch):
     tune.clear()
 
 
+def test_autotune_winner_is_keyed_on_the_call_mode(tmp_path, monkeypatch):
+    """A winner stored for a grid-mode ``fail_prob`` call is not handed to
+    a row-sum call or a two-channel call of the same bucket: each mode
+    sweeps for itself, and the persisted key carries the mode."""
+    monkeypatch.setenv("REPRO_AUTOTUNE", "1")
+    tune.clear()
+    row_src = RNG.integers(0, 64, 64).astype(np.int32)
+    d_mat = np.linspace(0.1, 1.0, 2).astype(np.float32)
+    cf = np.array([3.9, 2.1, 0.4, 0.8, 0.4, 7.5, 0.15, 3e-6, 3.5,
+                   1.2, 4.0, 0.4, 1.0, 0.3, 1.2], np.float32)
+    tag, bucket = "cpu-pallas-interpret", tune.bucket_pow2(64)
+    before = _sweeps("fail_prob", tag)
+    with ops.force_backend(tag):
+        ops.fail_prob(row_src, d_mat, cf[:9], cols=16)
+        assert tune.lookup("fail_prob", tag, bucket) is not None
+        for mode, kw in (("reduce_rows", dict(reduce_rows=True)),
+                         ("voltage+retention",
+                          dict(voltage=True, retention=True))):
+            assert tune.lookup("fail_prob", tag, bucket, mode) is None
+            ops.fail_prob(row_src, d_mat, cf, cols=16, **kw)
+            assert tune.lookup("fail_prob", tag, bucket, mode) is not None
+    assert _sweeps("fail_prob", tag) == before + 3
+    modes = ("", "reduce_rows", "voltage+retention")
+    assert sorted(tune._TUNE_CACHE) == [("fail_prob", tag, bucket, mode)
+                                        for mode in modes]
+    wins = {mode: tune.lookup("fail_prob", tag, bucket, mode)
+            for mode in modes}
+    path = tune.save_cache(tmp_path / "TUNE_kernels.json")
+    assert sorted(json.loads(path.read_text())) == [
+        f"fail_prob|{tag}|{bucket}|{mode}" for mode in modes]
+    tune.clear()
+    assert tune.load_cache(path) == 3
+    assert {mode: tune.lookup("fail_prob", tag, bucket, mode)
+            for mode in modes} == wins
+    tune.clear()
+
+
 def test_bucket_pow2():
     assert [tune.bucket_pow2(n) for n in (1, 2, 3, 100, 128, 129)] == \
         [1, 2, 4, 128, 128, 256]
@@ -238,9 +274,8 @@ def _tiny_call(name):
     """(args, kwargs minus ``interpret``) of a small call to ``name``."""
     i32 = lambda *shape: np.zeros(shape, np.int32)
     f32 = lambda *shape: np.zeros(shape, np.float32)
-    if name in ("fail_prob", "fail_prob_op"):
-        return (i32(8), f32(2), f32(9 if name == "fail_prob" else 15)), \
-            dict(cols=8)
+    if name == "fail_prob":
+        return (i32(8), f32(2), f32(9)), dict(cols=8)
     if name == "bank_sched":
         q, b, r = i32(8), i32(4), i32(2)
         return ((q, q, q, q, q, b, b, b, r, r, r, i32(1), i32(4, 6), b, b),

@@ -164,12 +164,11 @@ def test_fail_prob_row_tiles_match_oracle_to_float_tolerance(row_tile):
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-3)
     op_coeffs = np.concatenate(
         [coeffs, np.array([1.2, 4.0, 0.4, 1.0, 0.3, 1.2], np.float32)])
-    want_op = np.asarray(ref.fail_prob_op(row_src, d_mat, op_coeffs,
-                                          cols=128, voltage=True,
-                                          retention=True))
-    got_op = np.asarray(ops.fail_prob_op(row_src, d_mat, op_coeffs, cols=128,
-                                         voltage=True, retention=True,
-                                         row_tile=row_tile, pallas=True))
+    want_op = np.asarray(ref.fail_prob(row_src, d_mat, op_coeffs, cols=128,
+                                       voltage=True, retention=True))
+    got_op = np.asarray(ops.fail_prob(row_src, d_mat, op_coeffs, cols=128,
+                                      voltage=True, retention=True,
+                                      row_tile=row_tile, pallas=True))
     np.testing.assert_allclose(got_op, want_op, atol=1e-5, rtol=1e-3)
 
 
@@ -222,6 +221,32 @@ def test_fail_prob_row_sums_match_oracle(shape, tiles):
                                    reduce_rows=True, **tiles))
         assert got.shape == (R,)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=0)
+
+
+@pytest.mark.parametrize(
+    "tiles", ROW_SUM_TILES,
+    ids=[str(t.get("row_tile", "default")) for t in ROW_SUM_TILES])
+def test_fail_prob_row_sums_with_both_channels_match_oracle(tiles):
+    """The row-sum mode with the supply shift and the retention channel on
+    (15-wide row) against the oracle's row sums, to the same 1e-5 as the
+    access channel alone: the flags reach the same ``cell_probs`` in both
+    output modes."""
+    R, M, C = ROW_SUM_SHAPES["rows200"]
+    rng = np.random.default_rng(9)
+    row_src = rng.integers(0, R, R).astype(np.int32)
+    d_mat = np.linspace(0.1, 1.0, M).astype(np.float32)
+    coeffs = np.array([3.9, 2.1, 0.4, 0.8, 0.4, 7.5, 0.15, 3e-6, 3.5,
+                       1.2, 4.0, 0.4, 1.0, 0.3, 1.2], np.float32)
+    flags = dict(voltage=True, retention=True)
+    oracle = jax.jit(ref.fail_prob, static_argnames=(
+        "cols", "open_bitline", "voltage", "retention", "reduce_rows"))
+    want = np.asarray(oracle(row_src, d_mat, coeffs, cols=C,
+                             reduce_rows=True, **flags))
+    got = np.asarray(fp_pallas(row_src, d_mat, coeffs, cols=C,
+                               interpret=True, reduce_rows=True, **tiles,
+                               **flags))
+    assert got.shape == (R,)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=0)
 
 
 def test_fail_prob_oracle_row_sums_are_the_grid_summed():

@@ -15,8 +15,9 @@ The contracts under test (see ARCHITECTURE.md "operating points"):
   * per-bank tables stay inside the whole-DIMM envelope on every axis, in
     each axis's safe direction (<= on descending timing/vdd, >= on the
     ascending refresh axis);
-  * the operating-point kernel triple (``fail_prob_op``) is value-identical
-    to ``fail_prob`` with both channel flags off.
+  * the ``fail_prob`` kernel with both channel flags off is
+    value-identical on a 15-wide operating-point row and on the 9-wide one
+    (``tests/test_fail_prob_substrate.py``).
 """
 import jax
 import numpy as np

@@ -34,7 +34,7 @@ GiB = 2 ** 30
 CHUNK = 64
 #: SECDED / shuffle rows: one scrub chunk of served-table words
 N_WORDS = 4096
-MAIN_PATH = ("fail_prob", "fail_prob_op", "bit_signature", "secded_encode",
+MAIN_PATH = ("fail_prob", "bit_signature", "secded_encode",
              "secded_syndrome", "bank_sched", "diva_shuffle")
 CASES = [(name, tiles) for name in MAIN_PATH
          for tiles in REGISTRY[name].tile_space]
@@ -61,13 +61,9 @@ def _full_width_args(name: str, sharding):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     g = FULL
-    if name in ("fail_prob", "fail_prob_op"):
-        n = N_COEFFS if name == "fail_prob" else N_OP_COEFFS
-        kw = dict(cols=g.cols_per_mat)
-        if name == "fail_prob_op":
-            kw.update(voltage=True, retention=True)
+    if name == "fail_prob":
         return (a((g.rows_per_mat,), jnp.int32), a((g.mats_x,), jnp.float32),
-                a((n,), jnp.float32)), kw
+                a((N_COEFFS,), jnp.float32)), dict(cols=g.cols_per_mat)
     if name == "bit_signature":
         nbits = int(np.log2(g.rows_per_mat))
         return (a((CHUNK * g.subarrays, g.rows_per_mat), jnp.int32),), \
@@ -96,6 +92,24 @@ def test_kernel_tile_compiles_for_v5e_at_full_width(one_chip, name, tiles):
     args, kw = _full_width_args(name, one_chip)
     fn = functools.partial(REGISTRY[name].pallas, interpret=False, **tiles,
                            **kw)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "tiles", REGISTRY["fail_prob"].tile_space,
+    ids=[f"row_tile{t['row_tile']}" if t else "default"
+         for t in REGISTRY["fail_prob"].tile_space])
+def test_fail_prob_two_channel_grid_compiles_for_v5e_at_full_width(one_chip,
+                                                                   tiles):
+    """The grid mode of ``fail_prob`` with the supply shift and the
+    retention channel on, reading the 15-wide operating-point row from
+    SMEM, at every tile of its registry space and FULL widths."""
+    args, kw = _full_width_args("fail_prob", one_chip)
+    args = args[:2] + (jax.ShapeDtypeStruct((N_OP_COEFFS,), jnp.float32,
+                                            sharding=one_chip),)
+    fn = functools.partial(REGISTRY["fail_prob"].pallas, interpret=False,
+                           voltage=True, retention=True, **tiles, **kw)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
