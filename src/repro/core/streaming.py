@@ -57,8 +57,9 @@ from repro.core.substrate import (DimmBatch, _LEAVES, _axis_context,
                                   _resolve_rows, _row_lambda_impl,
                                   _run_sharded, _shuffling_impl,
                                   condition_adders, donation_enabled,
-                                  lifetime_adders, operating_grid_tables,
-                                  pattern_stress, sweep_steps)
+                                  erf_evals, lifetime_adders,
+                                  operating_grid_tables, pattern_stress,
+                                  sweep_steps)
 from repro.core.timing import PARAMS, VDD_STD
 from repro.obs import REGISTRY as _OBS_REGISTRY
 from repro.sharding import chunk_spans
@@ -855,12 +856,13 @@ def stream_secded_scrub(source, n_words: int | None = None, *,
 
 def _campaign_impl(batch: DimmBatch, t_op, stress, adder, *, pidx: int,
                    iters: int, seed: int, internal: bool, pallas: bool):
-    lam = _row_lambda_impl(batch, t_op, stress, adder, pidx=pidx,
-                           iters=iters, internal=internal, pallas=pallas)
+    lam, evals = _row_lambda_impl(batch, t_op, stress, adder, pidx=pidx,
+                                  iters=iters, internal=internal,
+                                  pallas=pallas)
     base = jax.random.PRNGKey(seed)
     keys = jax.vmap(lambda s: jax.random.fold_in(base, s.astype(jnp.int32)))(
         batch.serial)
-    return jax.vmap(lambda k, l: jax.random.poisson(k, l))(keys, lam)
+    return jax.vmap(lambda k, l: jax.random.poisson(k, l))(keys, lam), evals
 
 
 def hash_poisson_counts(batch: DimmBatch, param: str, t_op: float, *,
@@ -879,9 +881,11 @@ def hash_poisson_counts(batch: DimmBatch, param: str, t_op: float, *,
     adder = jnp.asarray(condition_adders(batch, temp_C, refresh_ms))
     statics = dict(pidx=PARAMS.index(param), iters=iters, seed=seed,
                    internal=False, pallas=ops.use_pallas())
-    counts = _chunk_call("stream_campaign", _campaign_impl,
-                         (batch, np.float32(t_op), stress, adder), statics,
-                         donate=(0, 3), batch_argnums=(0, 3), mesh=mesh)
+    counts, evals = _chunk_call("stream_campaign", _campaign_impl,
+                                (batch, np.float32(t_op), stress, adder),
+                                statics, donate=(0, 3), batch_argnums=(0, 3),
+                                mesh=mesh)
+    erf_evals("stream_campaign", evals)
     return np.asarray(counts, np.int64).reshape(
         batch.n_dimms, g.subarrays, g.rows_per_mat)
 

@@ -677,6 +677,26 @@ _OBS_SWEEP_STEPS = _OBS_REGISTRY.counter(
     labelnames=("entry", "param", "outcome"))
 
 
+# Campaign work: per call of a row-lambda program, the access channel's
+# failure terms the row-sum kernel evaluated and those it skipped as
+# exactly 0 or 1 (evaluated + saturated = twice the tiles times the mats),
+# in (row block x column strip) tiles of one mat and one term, read off
+# the program's count output.
+_OBS_ERF_EVALS = _OBS_REGISTRY.counter(
+    "repro_campaign_erf_evals_total",
+    "failure terms of the row-sum fail_prob kernel, in tiles of one mat "
+    "and one term, evaluated or skipped as saturated (exactly 0 or 1)",
+    labelnames=("entry", "outcome"))
+
+
+def erf_evals(entry: str, evals) -> None:
+    """Count one row-lambda call's failure terms from its (D, 2)
+    (evaluated, saturated) output, summed over every DIMM the kernel ran."""
+    n = np.asarray(evals, np.int64).sum(axis=0)
+    _OBS_ERF_EVALS.labels(entry=entry, outcome="evaluated").inc(int(n[0]))
+    _OBS_ERF_EVALS.labels(entry=entry, outcome="saturated").inc(int(n[1]))
+
+
 def sweep_steps(entry: str, steps) -> None:
     """Count one profiling call's grid points from ``_profile_impl``'s
     (D, 4) step output.  Every row holds its loop's count; under a mesh
@@ -1227,25 +1247,32 @@ def _row_lambda_impl(batch: DimmBatch, t_op, stress, adder, *,
     d_mat = jnp.asarray(d_mat)
     row_sums = functools.partial(ops.fail_prob_batch, cols=g.cols_per_mat,
                                  reduce_rows=True, pallas=pallas)  # over DIMMs
+    row_evals = functools.partial(ops.fail_prob_erf_evals,
+                                  cols=g.cols_per_mat, pallas=pallas)
+    D, R = batch.serial.shape[0], g.rows_per_mat
 
-    def per_subarray(_, s):
-        def per_pattern(acc_p, pi):
+    def per_subarray(evals, s):
+        def per_pattern(carry, pi):
+            acc_p, evals = carry
             coeffs = _pack_coeffs(batch, pidx, t_op, stress[pi], adder, 0, s)
-            sums = row_sums(jnp.take(batch.row_src, s, axis=1), d_mat, coeffs)
-            return acc_p + 2 * sums * g.chips, None
-        D, R = batch.serial.shape[0], g.rows_per_mat
-        exp_row, _ = jax.lax.scan(per_pattern, jnp.zeros((D, R), jnp.float32),
-                                  jnp.arange(P))
-        return None, exp_row * iters                     # (D, R) per subarray
+            row_src = jnp.take(batch.row_src, s, axis=1)
+            sums = row_sums(row_src, d_mat, coeffs)
+            evals = evals + row_evals(row_src, d_mat, coeffs)
+            return (acc_p + 2 * sums * g.chips, evals), None
+        (exp_row, evals), _ = jax.lax.scan(
+            per_pattern, (jnp.zeros((D, R), jnp.float32), evals),
+            jnp.arange(P))
+        return evals, exp_row * iters                    # (D, R) per subarray
 
-    _, lam = jax.lax.scan(per_subarray, None, jnp.arange(S))  # (S, D, R)
+    evals, lam = jax.lax.scan(per_subarray, jnp.zeros((D, 2), jnp.int32),
+                              jnp.arange(S))             # (S, D, R)
     lam = jnp.moveaxis(lam, 0, 1)                        # (D, S, R)
     if not internal:
         # counts are produced in internal order then scattered to external
         # addressing: ext_counts[j] = counts[ext_to_int[j]]
         lam = jnp.take_along_axis(lam, batch.ext_to_int[:, None, :]
                                   .repeat(lam.shape[1], axis=1), axis=2)
-    return lam.reshape(lam.shape[0], -1)
+    return lam.reshape(lam.shape[0], -1), evals
 
 
 _row_lambda_jit = functools.partial(
@@ -1266,9 +1293,11 @@ def row_error_lambda(batch: DimmBatch, param: str, t_op: float, *,
             jnp.asarray(adder))
     statics = dict(pidx=PARAMS.index(param), iters=iters,
                    internal=internal_order, pallas=ops.use_pallas())
-    out = _dispatch("row_lambda", mesh, _row_lambda_impl, _row_lambda_jit,
-                    args, statics, batch_argnums=(0, 3))
-    return np.asarray(out)
+    lam, evals = _dispatch("row_lambda", mesh, _row_lambda_impl,
+                           _row_lambda_jit, args, statics,
+                           batch_argnums=(0, 3))
+    erf_evals("row_lambda", evals)
+    return np.asarray(lam)
 
 
 # ----------------------------------------------- batched DIVA Shuffling (Fig 17)

@@ -39,7 +39,9 @@ import functools
 import os
 
 import jax
+import jax.numpy as jnp
 
+from repro.kernels import fail_prob as _fail_prob_mod
 from repro.kernels import tune as _tune
 from repro.kernels.registry import GPU, KERNEL_NAMES, REGISTRY, TPU
 from repro.obs import REGISTRY as _OBS_REGISTRY
@@ -219,6 +221,26 @@ def fail_prob_batch(row_src, d_mat, coeffs, *, pallas: bool | None = None,
         row_src, d_mat, coeffs)
 
 
+def fail_prob_erf_evals(row_src, d_mat, coeffs, *, cols: int,
+                        open_bitline: bool = True, voltage: bool = False,
+                        pallas: bool | None = None):
+    """(D, 2) int32 per DIMM: the access channel's failure terms that
+    ``fail_prob_batch(..., reduce_rows=True)`` on the same arguments
+    evaluates and those it skips as exactly 0 or 1, in tiles of one mat and
+    one term (``kernels/fail_prob.erf_evals``).  The kernel's route counts
+    from the mode table it reads, at the default tile that a traced call
+    takes; the oracle's route evaluates every term."""
+    route, _ = _resolve(REGISTRY["fail_prob"], pallas)
+    fn = functools.partial(_fail_prob_mod.erf_evals, cols=cols,
+                           open_bitline=open_bitline, voltage=voltage)
+    evals = jax.vmap(fn, in_axes=REGISTRY["fail_prob"].batch_in_axes)(
+        row_src, d_mat, coeffs)
+    if route == "ref":
+        evals = jnp.stack([evals.sum(axis=1), jnp.zeros_like(evals[:, 0])],
+                          axis=1)
+    return evals
+
+
 def bit_signature(counts, *, nbits: int, tile: int | None = None,
                   pallas: bool | None = None):
     """(N, R) int32 counts -> (N, nbits) int32 per-bit signature sums.
@@ -264,4 +286,4 @@ def wkv6(r, k, v, wlog, u, *, tile_bh: int | None = None,
 
 __all__ = ["backend_tag", "force_backend", "use_pallas", "interpret_mode",
            "valid_tags", "KERNEL_NAMES", *KERNEL_NAMES,
-           "fail_prob_batch"]
+           "fail_prob_batch", "fail_prob_erf_evals"]

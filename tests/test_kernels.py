@@ -1,9 +1,18 @@
 """Per-kernel shape/dtype sweeps against the pure-jnp oracles (interpret mode)."""
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.latency import fail_probability
 from repro.kernels import ops, ref
+from repro.kernels import fail_prob as fp_mod
 from repro.kernels.fail_prob import fail_prob as fp_pallas
 from repro.kernels.rc_transient import rc_transient as rc_pallas
 from repro.kernels.registry import REGISTRY
@@ -284,3 +293,155 @@ def test_wkv6_tile_variants_within_tolerance(tile_bh, chunk):
     tiled = np.asarray(ops.wkv6(r, k, v, w, u, tile_bh=tile_bh, chunk=chunk,
                                 pallas=True), np.float32)
     np.testing.assert_allclose(tiled, base, rtol=3e-4, atol=3e-4)
+
+
+# Row-sum cases of (rows, mats, cols) = (200, 16, 1024), one repaired row.
+# Strips step 2 ns apart (k_wl 16), so tiles lie at many z.  At t_op 7.5 ns
+# every mode of ``saturation_modes`` engages; at 11.5 ns no term reaches 1,
+# the sums stay small, and terms near the bound would show if a tile past
+# it were not exactly 0.
+SATURATION_CASE = """
+import numpy as np
+row_src = np.arange(200, dtype=np.int32)
+row_src[150] = 101
+d_mat = np.linspace(0.0, 1.0, 16).astype(np.float32)
+cols = 1024
+coeff_rows = {t_op: np.array([-5.75, 0.1, 16.0, 0.3, 0.6, t_op, 0.15, 3e-6,
+                              2.0], np.float32) for t_op in (7.5, 11.5)}
+"""
+# sha256 of the row sums of the kernel that evaluated every term (its last
+# form before the skip), per (t_op, bitline mode), the same at every tile
+# of ROW_SUM_TILES, evaluated op by op (see below)
+ROW_SUM_DIGESTS = {
+    (7.5, True):
+        "3f11888b72ed0cfe44abc62d4b0e00385b10f9eaccc4e333ccb30d6948081dfa",
+    (7.5, False):
+        "b86ecbd4e786caabd813b9e9481f2e14c3d7b1a02b3975f59077a5b703e1269c",
+    (11.5, True):
+        "52f96aca6429aac6c8704481ad8807c8dd00d94671f511553ba221401a2b19f4",
+    (11.5, False):
+        "1b812882edcc8433347409e6277cc85d467bad4e0d33c06f0541241edc59884b",
+}
+
+
+def _saturation_case():
+    ns = {}
+    exec(SATURATION_CASE, ns)
+    return ns["row_src"], ns["d_mat"], ns["coeff_rows"], ns["cols"]
+
+
+def test_fail_prob_row_sums_keep_the_digest_of_the_unskipped_kernel():
+    """Skipping the saturated terms changes no bit of the row sums, in both
+    bitline modes and at every tile of ROW_SUM_TILES.
+
+    The host's compiler contracts a multiply and the add that consumes it
+    into one fused multiply-add wherever they share a fusion, and the
+    skipping kernel's branches cut fusions where the unskipped kernel had
+    none, so on an FMA host the two round a few sums differently.  The
+    digests were taken, and are checked, in a process whose compiler is
+    held below FMA (``--xla_cpu_max_isa=AVX``): every operation rounds on
+    its own, as the formula writes it."""
+    code = SATURATION_CASE + f"""
+import hashlib, json
+from repro.kernels.fail_prob import fail_prob
+tiles = {ROW_SUM_TILES!r}
+print(json.dumps({{f"{{t_op}}|{{ob}}": [hashlib.sha256(np.asarray(fail_prob(
+    row_src, d_mat, cf, cols=cols, open_bitline=ob, interpret=True,
+    reduce_rows=True, **t)).tobytes()).hexdigest() for t in tiles]
+    for t_op, cf in coeff_rows.items() for ob in (True, False)}}))
+"""
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_cpu_max_isa=AVX", PYTHONPATH=str(src))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    digests = json.loads(out.stdout.strip().splitlines()[-1])
+    for (t_op, ob), want in ROW_SUM_DIGESTS.items():
+        assert digests[f"{t_op}|{ob}"] == [want] * len(ROW_SUM_TILES), \
+            (t_op, ob)
+
+
+@pytest.mark.parametrize("open_bitline", [True, False])
+def test_fail_prob_mode_table_counts_every_tile_and_engages_every_mode(
+        open_bitline):
+    """The count beside the kernel sums the mode table it reads, one mode
+    per (row block, column strip) tile for all its mats: evaluated plus
+    saturated terms are twice the tiles times the mats, and the case the
+    digest pins at 7.5 ns engages all five modes."""
+    row_src, d_mat, coeff_rows, cols = _saturation_case()
+    coeffs = coeff_rows[7.5]
+    for tiles in ROW_SUM_TILES:
+        row_tile = tiles.get("row_tile")
+        layout = fp_mod._row_sum_layout(row_src, d_mat, coeffs, cols,
+                                        open_bitline, False, row_tile)
+        modes = np.asarray(layout[-1]).ravel()
+        n_tiles = layout[0].shape[0] // 64 * (cols // 128)
+        assert modes.size == n_tiles
+        assert set(np.unique(modes)) == set(range(len(fp_mod.MODE_EVALS)))
+        evaluated, saturated = np.asarray(fp_mod.erf_evals(
+            row_src, d_mat, coeffs, cols=cols, open_bitline=open_bitline,
+            row_tile=row_tile))
+        assert evaluated + saturated == 2 * n_tiles * d_mat.size
+        assert evaluated == d_mat.size * sum(fp_mod.MODE_EVALS[m]
+                                             for m in modes)
+        assert 0 < evaluated < 2 * n_tiles * d_mat.size
+
+
+def test_fail_prob_erf_evals_count_all_terms_on_the_oracle_route():
+    """The oracle skips nothing, so its route counts every term evaluated;
+    the kernel's route counts from the mode table."""
+    row_src, d_mat, coeff_rows, cols = _saturation_case()
+    coeffs = coeff_rows[7.5]
+    batch = (row_src[None], d_mat, coeffs[None])
+    kernel = np.asarray(ops.fail_prob_erf_evals(*batch, cols=cols,
+                                                pallas=True))
+    oracle = np.asarray(ops.fail_prob_erf_evals(*batch, cols=cols,
+                                                pallas=False))
+    np.testing.assert_array_equal(kernel[0], fp_mod.erf_evals(
+        row_src, d_mat, coeffs, cols=cols))
+    np.testing.assert_array_equal(oracle[0], [kernel[0].sum(), 0])
+
+
+def _saturation_z():
+    """z on a dense grid past both thresholds, as t - t_op at sigma 1."""
+    z = np.linspace(-40.0, 40.0, 1_600_001, dtype=np.float32)
+    return z[np.abs(z) >= fp_mod.Z_SATURATED]
+
+
+def _assert_saturated(z, p):
+    p = np.asarray(p)
+    assert np.all(p[z <= -fp_mod.Z_SATURATED] == 0.0)
+    assert np.all(p[z >= fp_mod.Z_SATURATED] == 1.0)
+
+
+def test_fail_probability_is_exactly_0_or_1_past_the_saturation_z():
+    """The rule the kernel's skip rests on: past |z| = Z_SATURATED the
+    failure probability is exactly 0.0 or 1.0 in float32, at any t_op and
+    sigma that give the same z."""
+    z = _saturation_z()
+    for t_op, sigma in ((0.0, 1.0), (7.5, 0.15), (5.0, 0.2)):
+        t = jnp.asarray(z * np.float32(sigma) + np.float32(t_op))
+        zz = np.asarray((t - t_op) / sigma)
+        _assert_saturated(zz, jax.jit(
+            lambda t: fail_probability(t, t_op, sigma, xp=jnp))(t))
+
+
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="checks the chip's own float32 arithmetic")
+def test_fail_probability_saturates_inside_a_pallas_kernel_on_the_chip():
+    """The same rule inside a Pallas kernel compiled for the chip, whose
+    exp, divide and reciprocal are the chip's own."""
+    from jax.experimental import pallas as pl
+    z = _saturation_z()
+    z = np.pad(z, (0, (-z.size) % 1024)).reshape(-1, 128)
+
+    def kernel(z_ref, out_ref):
+        out_ref[...] = fail_probability(z_ref[...], 0.0, 1.0, xp=jnp)
+
+    p = pl.pallas_call(kernel, grid=(z.shape[0] // 8,),
+                       in_specs=[pl.BlockSpec((8, 128), lambda i: (i, 0))],
+                       out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)),
+                       out_shape=jax.ShapeDtypeStruct(z.shape, jnp.float32),
+                       )(jnp.asarray(z))
+    _assert_saturated(z, p)
